@@ -127,7 +127,6 @@ def compiled_terms(batch: int) -> Dict[str, float]:
     import jax.numpy as jnp
 
     from repro.configs import get_config
-    from repro.core.hlo_analysis import cost_analysis_dict
     from repro.optim.optimizer import SGD
     from repro.train.loop import (TrainStepConfig, build_train_step,
                                   init_train_state)
@@ -140,7 +139,7 @@ def compiled_terms(batch: int) -> Dict[str, float]:
     batch_abs = {"features": jax.ShapeDtypeStruct((batch, WIDTH), jnp.float32),
                  "click": jax.ShapeDtypeStruct((batch,), jnp.float32)}
     compiled = jax.jit(step).lower(state_abs, batch_abs).compile()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     n_params = sum(x.size for x in jax.tree_util.tree_leaves(state_abs.params))
     return {"flops": float(cost["flops"]),
             "bytes": float(cost.get("bytes accessed", 0.0)),

@@ -10,19 +10,18 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_reduced
-from repro.optim.optimizer import AdamW
 from repro.serve.engine import greedy_generate
-from repro.train.loop import init_train_state
+from repro.train.loop import init_params
 
 
 def demo(arch: str, steps: int = 24):
     cfg = get_reduced(arch).replace(compute_dtype=jnp.float32)
-    params = init_train_state(jax.random.PRNGKey(0), cfg, AdamW()).params
+    params = init_params(jax.random.PRNGKey(0), cfg)
     prompt = jax.random.randint(jax.random.PRNGKey(1), (4, 8), 0,
                                 cfg.vocab_size)
     t0 = time.perf_counter()
-    out = greedy_generate(params, cfg, prompt, steps=steps,
-                          max_len=8 + steps)
+    out, _ = greedy_generate(params, cfg, prompt, steps=steps,
+                             max_len=8 + steps)
     dt = time.perf_counter() - t0
     n_new = out.shape[1] - prompt.shape[1]
     print(f"{arch:<18} family={cfg.family:<7} batch=4  "

@@ -214,9 +214,11 @@ class HardwareSpec:
 
 # --- Presets -----------------------------------------------------------------
 
-#: TPU v5e — the target deployment chip for this framework.  Constants per the
-#: brief: 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.  The multi-pod
-#: ``pod`` axis rides data-center interconnect, modelled at 25 GB/s/chip.
+#: TPU v5e — the target deployment chip for this framework.  Peaks from the
+#: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at
+#: 819 GB/s, 1,600 Gbit/s of ICI per chip (~50 GB/s on each of 4 links).  The
+#: multi-pod ``pod`` axis rides data-center interconnect, modelled at
+#: 25 GB/s/chip.
 TPU_V5E = HardwareSpec(
     name="tpu_v5e",
     peak_flops=197e12,
@@ -240,6 +242,21 @@ CLX = HardwareSpec(
 )
 
 PRESETS: Dict[str, HardwareSpec] = {"tpu_v5e": TPU_V5E, "clx": CLX}
+
+#: ``jax.Device.device_kind`` -> the preset whose peaks describe that chip
+#: (JAX's own mesh code knows a v5e by either name)
+DEVICE_KIND_PRESETS: Dict[str, str] = {"TPU v5 lite": "tpu_v5e",
+                                       "TPU v5e": "tpu_v5e"}
+
+
+def hardware_for_device_kind(device_kind: str) -> HardwareSpec:
+    """Datasheet preset of a device as JAX names it; unlisted kinds raise."""
+    try:
+        return PRESETS[DEVICE_KIND_PRESETS[device_kind]]
+    except KeyError:
+        raise KeyError(
+            f"no peaks for device_kind {device_kind!r}; known kinds: "
+            f"{sorted(DEVICE_KIND_PRESETS)}") from None
 
 
 # --- calibration registry -----------------------------------------------------

@@ -309,40 +309,21 @@ def _memory_stats(mem_analysis) -> float:
     with donated arguments (the decode cache) — counting it in both args
     and outputs would double it.
     """
-    if not hasattr(mem_analysis, "temp_size_in_bytes"):
-        return 0.0
-    try:
-        total = (
-            getattr(mem_analysis, "temp_size_in_bytes", 0)
-            + getattr(mem_analysis, "argument_size_in_bytes", 0)
-            + getattr(mem_analysis, "output_size_in_bytes", 0)
-            + getattr(mem_analysis, "generated_code_size_in_bytes", 0)
-            - getattr(mem_analysis, "alias_size_in_bytes", 0)
-        )
-        return float(total)
-    except Exception:  # pragma: no cover
-        return 0.0
-
-
-def cost_analysis_dict(compiled) -> Dict:
-    """``compiled.cost_analysis()`` across jax versions (0.4.x: [dict])."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    return float(mem_analysis.temp_size_in_bytes
+                 + mem_analysis.argument_size_in_bytes
+                 + mem_analysis.output_size_in_bytes
+                 + mem_analysis.generated_code_size_in_bytes
+                 - mem_analysis.alias_size_in_bytes)
 
 
 def analyze_compiled(compiled, num_devices: int,
                      pod_size: int = 0) -> StepCosts:
     """Build StepCosts from a ``jax.stages.Compiled`` object."""
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     flops, mem = _extract_cost(cost)
     hlo = compiled.as_text()
     coll = parse_collectives(hlo, num_devices, pod_size=pod_size)
-    try:
-        peak = _memory_stats(compiled.memory_analysis())
-    except Exception:
-        peak = 0.0
+    peak = _memory_stats(compiled.memory_analysis())
     return StepCosts(
         flops=flops,
         mem_bytes=mem,

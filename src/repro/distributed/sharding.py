@@ -135,21 +135,26 @@ def shard_hint(x: jax.Array, axes: Sequence[Optional[str]]) -> jax.Array:
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
+def is_logical_axes(x: Any) -> bool:
+    """A spec leaf: a tuple of logical axis names (``None`` = replicated).
+
+    Named tuples of specs (``TrainState``, ``AdamWState``) are containers,
+    not leaves.
+    """
+    return isinstance(x, tuple) and all(
+        isinstance(a, (str, type(None))) for a in x)
+
+
 def specs_to_shardings(specs: Any, mesh: Mesh,
                        rules: Optional[Rules] = None) -> Any:
-    """Map a pytree of logical-axis tuples to NamedShardings.
-
-    Leaves are tuples of logical names; ``is_leaf`` keys on tuples so nested
-    dicts/lists of specs work.
-    """
+    """Map a pytree of logical-axis tuples to NamedShardings."""
     binding = _active()
     rules = rules or (binding[1] if binding else DEFAULT_RULES)
 
     def to_sharding(axes):
         return NamedSharding(mesh, logical_spec(axes, rules))
 
-    return jax.tree.map(to_sharding, specs,
-                        is_leaf=lambda x: isinstance(x, tuple))
+    return jax.tree.map(to_sharding, specs, is_leaf=is_logical_axes)
 
 
 def validate_divisibility(shapes: Any, shardings: Any) -> None:

@@ -8,8 +8,8 @@ elementwise HBM round-trip the paper's B_M accounting would otherwise pay
 TPU mapping: grid (M/bm, N/bn, K/bk) with the K dimension innermost so the
 fp32 VMEM accumulator carries across K steps; blocks default to 512×512×512
 (MXU-aligned multiples of 128; ~1.5 MiB of VMEM for bf16 operands + fp32
-accumulator, well inside the 16 MiB/core budget).  Validated on CPU with
-``interpret=True`` against ``ref.ref_matmul``.
+accumulator, well inside the 16 MiB/core budget).  Checked against
+``ref.ref_matmul`` in interpret mode on CPU and compiled on a TPU.
 """
 from __future__ import annotations
 
@@ -58,13 +58,15 @@ def blocked_matmul(a: jnp.ndarray, b: jnp.ndarray,
                    bias: Optional[jnp.ndarray] = None,
                    act: Optional[str] = None,
                    block_m: int = 512, block_n: int = 512, block_k: int = 512,
-                   interpret: bool = True) -> jnp.ndarray:
+                   interpret: Optional[bool] = None) -> jnp.ndarray:
     """a (M, K) @ b (K, N) [+ bias (N,)] with fused activation.
 
     Requires M % block_m == K % block_k == N % block_n == 0 (the ops.py
-    wrapper pads).  ``interpret=True`` runs the kernel body on CPU; on real
-    TPU pass interpret=False.
+    wrapper pads).  ``interpret=None`` compiles the kernel when the default
+    backend is a TPU and interprets it on any other backend.
     """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     if act is not None and act not in _ACTS:
         raise ValueError(f"unsupported activation {act}")
     M, K = a.shape

@@ -12,7 +12,8 @@ of a materialized mask.
 
 Layout contract (ops.py handles transposes): q (B, H, S, dh),
 k/v (B, K, S, dh) with H = G·K query groups per kv head.
-Validated on CPU with ``interpret=True`` against ``ref.ref_flash_attention``.
+Checked against ``ref.ref_flash_attention`` in interpret mode on CPU and
+compiled on a TPU.
 """
 from __future__ import annotations
 
@@ -76,11 +77,15 @@ def flash_attention_bhsd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                          causal: bool = True, window: int = 0,
                          seq_len: Optional[int] = None,
                          block_q: int = 256, block_k: int = 512,
-                         interpret: bool = True) -> jnp.ndarray:
+                         interpret: Optional[bool] = None) -> jnp.ndarray:
     """q (B,H,Sp,dh), k/v (B,K,Sp,dh), Sp padded to block multiples.
 
-    ``seq_len`` = true (unpadded) length for key masking.
+    ``seq_len`` = true (unpadded) length for key masking.  ``interpret=None``
+    compiles the kernel when the default backend is a TPU and interprets it
+    on any other backend.
     """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     B, H, Sp, dh = q.shape
     K = k.shape[1]
     G = H // K

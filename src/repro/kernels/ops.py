@@ -2,12 +2,14 @@
 
 Handles layout/padding glue so callers keep model-native shapes:
   * ``matmul``: collapses leading dims, pads (M, K, N) to block multiples,
-    slices back.  ``interpret=True`` on CPU (this container); compiled on TPU.
+    slices back.
   * ``flash_attention``: (B, S, H, dh) model layout -> (B, H, S, dh) kernel
     layout, pads S, restores.
 
 The wrappers fall back to the jnp reference for shapes where a kernel launch
-is not worth it (tiny matrices in smoke tests).
+is not worth it (tiny matrices in smoke tests).  Otherwise the kernels are
+compiled on a TPU backend and interpreted on any other, decided when the
+wrapper is traced.
 """
 from __future__ import annotations
 
@@ -20,9 +22,6 @@ import jax.numpy as jnp
 from repro.kernels import ref
 from repro.kernels.blocked_matmul import blocked_matmul
 from repro.kernels.flash_attention import flash_attention_bhsd
-
-#: flip on real TPU deployments (pallas compiles natively); interpret on CPU
-INTERPRET = jax.default_backend() != "tpu"
 
 _MIN_DIM = 256  # below this, kernel launch overhead > any win: use jnp
 
@@ -54,8 +53,7 @@ def matmul(a: jnp.ndarray, b: jnp.ndarray,
     b2 = _pad_to(_pad_to(b, block, 0), block, 1)
     bias2 = _pad_to(bias, block, 0) if bias is not None else None
     y = blocked_matmul(a2, b2, bias=bias2, act=act,
-                       block_m=block, block_n=block, block_k=block,
-                       interpret=INTERPRET)
+                       block_m=block, block_n=block, block_k=block)
     return y[:M, :N].reshape(*lead, N)
 
 
@@ -72,6 +70,5 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     vt = _pad_to(jnp.swapaxes(v, 1, 2), 512, 2)
     bq, bk = 256, 512
     o = flash_attention_bhsd(qt, kt, vt, causal=causal, window=window,
-                             seq_len=S, block_q=bq, block_k=bk,
-                             interpret=INTERPRET)
+                             seq_len=S, block_q=bq, block_k=bk)
     return jnp.swapaxes(o[:, :, :S], 1, 2)

@@ -4,28 +4,17 @@ Functions, not module-level constants: importing this module never touches
 jax device state.  The production target is a TPU v5e pod of 16×16 = 256
 chips; multi-pod doubles it with a leading "pod" axis (2 × 256 = 512 chips)
 riding data-center interconnect (see core/hardware.py extra_links).
-
-``AxisType`` landed in jax 0.5 (explicit-sharding work); on older jax every
-mesh axis is implicitly Auto, so the fallback simply omits the argument.
 """
 from __future__ import annotations
 
 from typing import Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-try:  # jax >= 0.5
-    from jax.sharding import AxisType
 
-    def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-except ImportError:  # jax <= 0.4.x: axes are Auto by default
-    AxisType = None
-
-    def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-        return jax.make_mesh(shape, axes)
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -37,19 +26,6 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
     """Arbitrary mesh for tests / elastic-reshard experiments."""
     return _auto_mesh(shape, axes)
-
-
-def make_abstract_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Device-free AbstractMesh across the 0.4/0.5 constructor change.
-
-    jax >= 0.5 takes ``(axis_sizes, axis_names)``; 0.4.x takes a single
-    tuple of ``(name, size)`` pairs.
-    """
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(shape, axes)
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
 
 
 def make_host_mesh() -> Mesh:

@@ -8,19 +8,30 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+from typing import Any, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config, get_reduced
 from repro.distributed.sharding import gqa_safe_rules, use_sharding
 from repro.launch.mesh import make_mesh
-from repro.optim.optimizer import AdamW
+from repro.models.common import ModelConfig
 from repro.serve.engine import greedy_generate
-from repro.train.loop import init_train_state
+from repro.train.loop import init_params
 
 
-def main(argv=None) -> int:
+class ServeRun(NamedTuple):
+    cfg: ModelConfig
+    params: Any
+    prompt: jnp.ndarray      # (B, prompt_len)
+    tokens: jnp.ndarray      # (B, prompt_len + new_tokens)
+    logits: jnp.ndarray      # (B, prompt_len + new_tokens - 1, V)
+    seconds: float           # wall time of the generation, compile included
+
+
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -29,8 +40,10 @@ def main(argv=None) -> int:
     ap.add_argument("--new-tokens", type=int, default=32)
     ap.add_argument("--mesh", default="1x1")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def run(args: argparse.Namespace) -> ServeRun:
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     if args.reduced:
         cfg = cfg.replace(compute_dtype=jnp.float32)
@@ -38,19 +51,26 @@ def main(argv=None) -> int:
     mesh = make_mesh(dims, ("data", "model"))
 
     with use_sharding(mesh, gqa_safe_rules(cfg.n_kv_heads, mesh)):
-        params = init_train_state(
-            jax.random.PRNGKey(args.seed), cfg, AdamW()).params
+        params = init_params(jax.random.PRNGKey(args.seed), cfg)
         prompt = jax.random.randint(
             jax.random.PRNGKey(args.seed + 1),
             (args.batch, args.prompt_len), 0, cfg.vocab_size)
         t0 = time.perf_counter()
-        out = greedy_generate(params, cfg, prompt, steps=args.new_tokens,
-                              max_len=args.prompt_len + args.new_tokens)
-        dt = time.perf_counter() - t0
-        tok_s = args.batch * args.new_tokens / dt
-        print(f"{args.arch}: batch={args.batch} +{args.new_tokens} tokens "
-              f"in {dt:.2f}s ({tok_s:.0f} tok/s)")
-        print("first sequence:", out[0].tolist())
+        tokens, logits = greedy_generate(
+            params, cfg, prompt, steps=args.new_tokens,
+            max_len=args.prompt_len + args.new_tokens)
+        seconds = time.perf_counter() - t0
+    return ServeRun(cfg, params, prompt, tokens, logits, seconds)
+
+
+def main(argv=None) -> int:
+    use_compile_cache()
+    args = parse_args(argv)
+    out = run(args)
+    tok_s = args.batch * args.new_tokens / out.seconds
+    print(f"{args.arch}: batch={args.batch} +{args.new_tokens} tokens "
+          f"in {out.seconds:.2f}s ({tok_s:.0f} tok/s)")
+    print("first sequence:", out.tokens[0].tolist())
     return 0
 
 

@@ -19,13 +19,11 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding
 
 from repro.configs.shapes import ShapeSpec
-from repro.distributed.sharding import logical_spec
-from repro.models import transformer as lm_mod
-from repro.models import encdec as encdec_mod
-from repro.models import vlm as vlm_mod
+from repro.distributed.sharding import is_logical_axes, logical_spec
 from repro.models.common import ModelConfig
 from repro.serve import engine as serve_engine
-from repro.train.loop import TrainState, init_train_state, model_param_specs
+from repro.train.loop import (TrainState, init_params, init_train_state,
+                              model_param_specs)
 
 
 def _sds(shape, dtype, mesh: Optional[Mesh], axes) -> jax.ShapeDtypeStruct:
@@ -68,10 +66,7 @@ def decode_input_specs(cfg: ModelConfig, shape: ShapeSpec,
 
 def abstract_params(cfg: ModelConfig):
     key = jax.ShapeDtypeStruct((2,), jnp.uint32)
-    from repro.models import mlp_dlrm as mlp_mod
-    init = {"encdec": encdec_mod.init_encdec, "vlm": vlm_mod.init_vlm,
-            "mlp": mlp_mod.init_mlp}.get(cfg.family, lm_mod.init_lm)
-    return jax.eval_shape(lambda k: init(k, cfg), key)
+    return jax.eval_shape(lambda k: init_params(k, cfg), key)
 
 
 def abstract_train_state(cfg: ModelConfig, optimizer) -> TrainState:
@@ -110,8 +105,7 @@ def attach(tree_abs, specs, mesh: Mesh):
             sharding=NamedSharding(mesh, spec))
 
     return jax.tree.map(one, tree_abs, specs,
-                        is_leaf=lambda x: isinstance(x, tuple) and all(
-                            isinstance(a, (str, type(None))) for a in x))
+                        is_leaf=is_logical_axes)
 
 
 def _augment_data_axis(pspecs):
@@ -135,8 +129,7 @@ def _augment_data_axis(pspecs):
         return axes
 
     return jax.tree.map(one, pspecs,
-                        is_leaf=lambda x: isinstance(x, tuple) and all(
-                            isinstance(a, (str, type(None))) for a in x))
+                        is_leaf=is_logical_axes)
 
 
 def train_state_specs(cfg: ModelConfig, zero1: bool = True,

@@ -6,33 +6,49 @@
 On the CPU container, use ``--reduced`` (CPU-sized config of the same
 family); on a real pod, omit it and pass ``--mesh data,model`` sizes.  The
 launcher wires together the full substrate: mesh + logical sharding rules,
-deterministic per-host data pipeline, AdamW with warmup-cosine, the
-fault-tolerant runner (auto-resume from the latest committed checkpoint,
-periodic async saves, straggler flags), and a closing Ridgeline report of
-the compiled step.
+state and batch placed on the mesh (replicated state, batch split over
+"data"), deterministic per-host data pipeline, AdamW with warmup-cosine,
+the fault-tolerant runner (auto-resume from the latest committed checkpoint
+in ``--ckpt-dir``, periodic async saves, straggler flags), and a closing
+Ridgeline report of the compiled step.
 """
 from __future__ import annotations
 
 import argparse
 import sys
+import time
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.checkpoint.checkpointer import Checkpointer
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config, get_reduced
-from repro.core import TPU_V5E, WorkUnit, analyze
+from repro.core import TPU_V5E, HardwareSpec, WorkUnit, analyze
+from repro.core.hardware import hardware_for_device_kind
 from repro.core.hlo_analysis import analyze_compiled
 from repro.data.pipeline import DataConfig, make_stream
-from repro.distributed.sharding import gqa_safe_rules, use_sharding
+from repro.distributed.sharding import (gqa_safe_rules, specs_to_shardings,
+                                        use_sharding)
 from repro.launch.mesh import make_mesh
+from repro.launch.specs import train_state_specs
+from repro.models.common import ModelConfig
 from repro.optim.optimizer import AdamW, warmup_cosine
 from repro.train.fault_tolerance import ResilientRunner, RunnerConfig
 from repro.train.loop import TrainStepConfig, build_train_step, init_train_state
 
 
-def main(argv=None) -> int:
+class TrainRun(NamedTuple):
+    state: Any
+    history: List[Dict[str, float]]   # one entry per step taken
+    compile_seconds: float            # lowering + compiling the step
+    report: str                       # Ridgeline report of the compiled step
+
+
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=200)
@@ -43,12 +59,41 @@ def main(argv=None) -> int:
                     help="CPU-sized config of the same family")
     ap.add_argument("--mesh", default="1x1",
                     help="data x model split, e.g. 16x16 on a pod")
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_train")
+    ap.add_argument("--ckpt-dir", required=True,
+                    help="checkpoint directory; a run resumes from the "
+                         "latest step committed there")
     ap.add_argument("--ckpt-every", type=int, default=100)
     ap.add_argument("--n-micro", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def report_peaks() -> Tuple[HardwareSpec, str]:
+    """Peaks to report the step against, and what they stand for."""
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        return (hardware_for_device_kind(dev.device_kind),
+                f"peaks of this device ({dev.device_kind})")
+    return TPU_V5E, (f"projection onto {TPU_V5E.name}, not a reading of "
+                     f"this {dev.platform} device")
+
+
+def jit_train_step(cfg: ModelConfig, opt, step_cfg: TrainStepConfig,
+                   mesh: Mesh):
+    """The launcher's jitted step and its state placement on ``mesh``.
+
+    The state is placed per ``train_state_specs`` without ZeRO (dlrm-mlp:
+    replicated) and the batch is split over "data".  Call inside
+    ``use_sharding(mesh, ...)``.
+    """
+    state_sh = specs_to_shardings(train_state_specs(cfg, zero1=False), mesh)
+    step = jax.jit(build_train_step(cfg, opt, step_cfg),
+                   in_shardings=(state_sh, NamedSharding(mesh, P("data"))),
+                   out_shardings=(state_sh, None), donate_argnums=(0,))
+    return step, state_sh
+
+
+def run(args: argparse.Namespace) -> TrainRun:
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     if args.reduced:
         cfg = cfg.replace(compute_dtype=jnp.float32)
@@ -59,11 +104,15 @@ def main(argv=None) -> int:
     step_cfg = TrainStepConfig(n_micro=args.n_micro)
 
     with use_sharding(mesh, gqa_safe_rules(cfg.n_kv_heads, mesh)):
-        train_step = jax.jit(build_train_step(cfg, opt, step_cfg),
-                             donate_argnums=(0,))
-        state = init_train_state(jax.random.PRNGKey(args.seed), cfg, opt)
+        train_step, state_sh = jit_train_step(cfg, opt, step_cfg, mesh)
+        state = jax.jit(lambda k: init_train_state(k, cfg, opt),
+                        out_shardings=state_sh)(
+            jax.random.PRNGKey(args.seed))
         stream = make_stream(cfg, DataConfig(
             seed=args.seed, global_batch=args.batch, seq_len=args.seq))
+        t0 = time.perf_counter()
+        compiled = train_step.lower(state, stream.batch(0)).compile()
+        compile_seconds = time.perf_counter() - t0
         runner = ResilientRunner(
             train_step, Checkpointer(args.ckpt_dir, keep=3),
             RunnerConfig(ckpt_every=args.ckpt_every),
@@ -72,23 +121,24 @@ def main(argv=None) -> int:
                 f"vs EWMA {ev.ewma:.2f}s", file=sys.stderr))
         state, history = runner.run(state, stream, n_steps=args.steps)
 
-        if history:
-            first = np.mean([h["ce"] for h in history[:10]])
-            last = np.mean([h["ce"] for h in history[-10:]])
-            print(f"steps {history[0]['step']}..{history[-1]['step']}  "
-                  f"CE {first:.4f} -> {last:.4f}")
+    costs = analyze_compiled(compiled, mesh.size)
+    hw, basis = report_peaks()
+    report = analyze(WorkUnit(f"{args.arch}/train", costs.flops,
+                              costs.mem_bytes, costs.wire_bytes),
+                     hw).summary()
+    return TrainRun(state, history, compile_seconds, f"{report} [{basis}]")
 
-        # closing Ridgeline report of the compiled step
-        batch_abs = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, jnp.asarray(x).dtype),
-            stream.batch(0))
-        state_abs = jax.eval_shape(lambda s: s, state)
-        compiled = jax.jit(build_train_step(cfg, opt, step_cfg)).lower(
-            state_abs, batch_abs).compile()
-        costs = analyze_compiled(compiled, mesh.size)
-        print(analyze(WorkUnit(f"{args.arch}/train", costs.flops,
-                               costs.mem_bytes, costs.wire_bytes),
-                      TPU_V5E).summary())
+
+def main(argv=None) -> int:
+    use_compile_cache()
+    result = run(parse_args(argv))
+    history = result.history
+    if history:
+        first = np.mean([h["ce"] for h in history[:10]])
+        last = np.mean([h["ce"] for h in history[-10:]])
+        print(f"steps {history[0]['step']}..{history[-1]['step']}  "
+              f"CE {first:.4f} -> {last:.4f}")
+    print(result.report)
     return 0
 
 

@@ -59,6 +59,7 @@ import os
 import sys
 from typing import Dict, List, Optional, Sequence, Tuple
 
+from repro.compile_cache import use_compile_cache
 from repro.core.hardware import (CALIBRATED_SUFFIX, CALIBRATION_SCHEMA,
                                  EfficiencyModel, HardwareSpec,
                                  calibration_dir, get_hardware)
@@ -667,7 +668,10 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         print(f"error: --name {args.name!r} shadows a datasheet preset; "
               f"pick another (default: {args.hardware}_cal)", file=sys.stderr)
         return 2
+    # the backend must be chosen before jax is imported, which turning on
+    # the compile cache does
     _configure_backend(args.backend, args.devices)
+    use_compile_cache()
 
     from repro.measure import microbench
     with trace.span("calibrate.suite", smoke=args.smoke,

@@ -21,7 +21,7 @@ Bench families and which resource they are built to saturate:
   * ``step_benches`` — whole jitted model steps on tiny configs: the
     dlrm-mlp train step (``train/loop``) and a reduced dense-LM decode step
     (``serve/engine``), with F/B_M read off the compiled HLO via
-    ``core/hlo_analysis.cost_analysis_dict``.  These are *validation*
+    ``compiled.cost_analysis()``.  These are *validation*
     points: the calibrate CLI fits ceilings on the micro suites and reports
     model-vs-measured error on the steps.
 
@@ -341,8 +341,7 @@ def collective_benches(sizes_mb: Sequence[int] = SMOKE_COLLECTIVE_MB, *,
 
 
 def _hlo_work_unit(name: str, compiled, net_bytes: float = 0.0) -> WorkUnit:
-    from repro.core.hlo_analysis import cost_analysis_dict
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     return WorkUnit(name,
                     flops=float(cost.get("flops", 0.0)),
                     mem_bytes=float(cost.get("bytes accessed", 0.0)),

@@ -8,7 +8,7 @@ sampling helper included for the runnable demos.
 """
 from __future__ import annotations
 
-from typing import Callable
+from typing import Callable, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -49,14 +49,19 @@ def init_cache(params, cfg: ModelConfig, batch: int, max_len: int,
 
 def greedy_generate(params, cfg: ModelConfig, prompt: jnp.ndarray,
                     steps: int, max_len: int,
-                    frames: jnp.ndarray | None = None) -> jnp.ndarray:
-    """Prefill token-by-token then greedy-decode ``steps`` tokens."""
+                    frames: jnp.ndarray | None = None
+                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Prefill token-by-token then greedy-decode ``steps`` tokens.
+
+    Returns the tokens (B, S + steps) and the decode logits
+    (B, S + steps - 1, V), whose column t was computed at position t.
+    """
     B, S = prompt.shape
     serve_step = jax.jit(build_serve_step(cfg))
     cache = init_cache(params, cfg, B, max_len, frames=frames)
     tok = prompt[:, :1]
     out = [tok]
-    logits = None
+    seen = []
     step_hist = REGISTRY.histogram("serve.step_seconds")
     with trace.span("serve.generate", arch=cfg.name, batch=B,
                     prompt_len=S, steps=steps):
@@ -66,9 +71,10 @@ def greedy_generate(params, cfg: ModelConfig, prompt: jnp.ndarray,
             with step_hist.time():
                 logits, cache = serve_step(params, tok, cache, jnp.int32(t))
                 block_until_ready(logits)
+            seen.append(logits[:, -1])
             if t + 1 < S:
                 tok = prompt[:, t + 1:t + 2]
             else:
                 tok = jnp.argmax(logits[:, -1:], axis=-1).astype(prompt.dtype)
             out.append(tok)
-    return jnp.concatenate(out, axis=1)
+    return jnp.concatenate(out, axis=1), jnp.stack(seen, axis=1)

@@ -121,7 +121,7 @@ class ResilientRunner:
                     self._track_time(step, dt)
                     history.append(
                         {k: float(v) for k, v in metrics.items()}
-                        | {"step": step})
+                        | {"step": step, "seconds": dt})
                     step += 1
                     if step % self.cfg.ckpt_every == 0:
                         self.ckpt.save(step, state,

@@ -125,16 +125,20 @@ def build_train_step(cfg: ModelConfig, optimizer,
     return train_step
 
 
+def init_params(key, cfg: ModelConfig):
+    """The model's parameters alone (serving needs no optimizer state)."""
+    if cfg.family == "encdec":
+        return encdec_mod.init_encdec(key, cfg)
+    if cfg.family == "vlm":
+        return vlm_mod.init_vlm(key, cfg)
+    if cfg.family == "mlp":
+        return mlp_mod.init_mlp(key, cfg)
+    return lm_mod.init_lm(key, cfg)
+
+
 def init_train_state(key, cfg: ModelConfig, optimizer) -> TrainState:
     with trace.span("train.init_state", arch=cfg.name, family=cfg.family):
-        if cfg.family == "encdec":
-            params = encdec_mod.init_encdec(key, cfg)
-        elif cfg.family == "vlm":
-            params = vlm_mod.init_vlm(key, cfg)
-        elif cfg.family == "mlp":
-            params = mlp_mod.init_mlp(key, cfg)
-        else:
-            params = lm_mod.init_lm(key, cfg)
+        params = init_params(key, cfg)
         return TrainState(params=params, opt_state=optimizer.init(params),
                           step=jnp.zeros((), jnp.int32), rng=key)
 

@@ -104,8 +104,7 @@ class TestPerDeviceSemantics:
             x = jax.ShapeDtypeStruct((1024, 512), jnp.float32, sharding=s)
             w = jax.ShapeDtypeStruct((512, 256), jnp.float32)
             c = jax.jit(lambda x, w: x @ w).lower(x, w).compile()
-            from repro.core.hlo_analysis import cost_analysis_dict
-            flops = cost_analysis_dict(c)["flops"]
+            flops = c.cost_analysis()["flops"]
             total = 2 * 1024 * 512 * 256
             assert abs(flops - total / 8) / total < 0.01, flops
             print("PER_DEVICE_OK")
@@ -135,7 +134,6 @@ def test_scan_body_counted_once():
     scan = jax.jit(lambda x, w: jax.lax.scan(body, x, w)[0]).lower(x, w).compile()
     unroll = jax.jit(lambda x, w: jax.lax.scan(body, x, w, unroll=8)[0]
                      ).lower(x, w).compile()
-    from repro.core.hlo_analysis import cost_analysis_dict
-    f_scan = cost_analysis_dict(scan)["flops"]
-    f_unroll = cost_analysis_dict(unroll)["flops"]
+    f_scan = scan.cost_analysis()["flops"]
+    f_unroll = unroll.cost_analysis()["flops"]
     assert f_unroll == pytest.approx(8 * f_scan, rel=0.01)

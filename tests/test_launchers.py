@@ -23,3 +23,26 @@ def test_serve_launcher(capsys):
                "--prompt-len", "4", "--new-tokens", "6"])
     assert rc == 0
     assert "tok/s" in capsys.readouterr().out
+
+
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/cache"])
+def test_compile_cache_placement(monkeypatch, env_dir):
+    """The entry points' cache: JAX's own variable wins, else .jax_cache/."""
+    import jax
+    from repro.compile_cache import CACHE_DIR, use_compile_cache
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        use_compile_cache()
+        after = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    if env_dir is None:
+        assert after == str(CACHE_DIR)
+        assert CACHE_DIR.name == ".jax_cache"
+        assert (CACHE_DIR.parent / "chip_smoke.py").exists()
+    else:
+        assert after == before      # left to JAX, which reads the variable

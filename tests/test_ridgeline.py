@@ -121,6 +121,25 @@ class TestMultilink:
         assert a.t_network == pytest.approx(6e8 / 25e9)
 
 
+class TestDeviceKindLookup:
+    @pytest.mark.parametrize("kind", ["TPU v5 lite", "TPU v5e"])
+    def test_v5e_kind_maps_to_its_preset(self, kind):
+        from repro.core.hardware import hardware_for_device_kind
+        assert hardware_for_device_kind(kind) is TPU_V5E
+
+    @pytest.mark.parametrize("kind", ["TPU v4", "TPU v6 lite", "cpu"])
+    def test_unlisted_kind_raises(self, kind):
+        from repro.core.hardware import hardware_for_device_kind
+        with pytest.raises(KeyError, match="no peaks for device_kind"):
+            hardware_for_device_kind(kind)
+
+    def test_report_on_cpu_is_a_projection(self):
+        from repro.launch.train import report_peaks
+        hw, basis = report_peaks()
+        assert hw is TPU_V5E
+        assert basis.startswith("projection onto tpu_v5e")
+
+
 class TestPlots:
     def test_ascii_plot_renders_regions_and_points(self):
         a = analyze(WorkUnit("pt", 1e12, 1e10, 1e8), CLX)

@@ -8,8 +8,7 @@ from repro.configs import get_reduced
 from repro.models import encdec as encdec_mod
 from repro.models import transformer as lm_mod
 from repro.serve.engine import build_serve_step, greedy_generate, init_cache
-from repro.train.loop import init_train_state
-from repro.optim.optimizer import AdamW
+from repro.train.loop import init_params
 
 KEY = jax.random.PRNGKey(7)
 
@@ -20,7 +19,7 @@ EXACT = ["qwen2.5-3b", "smollm-135m", "minitron-8b", "qwen2-7b",
 
 
 def _params(cfg):
-    return init_train_state(KEY, cfg, AdamW()).params
+    return init_params(KEY, cfg)
 
 
 @pytest.mark.slow
@@ -79,11 +78,15 @@ def test_greedy_generate_is_deterministic_and_extends():
     cfg = get_reduced("smollm-135m").replace(compute_dtype=jnp.float32)
     params = _params(cfg)
     prompt = jax.random.randint(KEY, (2, 5), 0, cfg.vocab_size)
-    out1 = greedy_generate(params, cfg, prompt, steps=4, max_len=16)
-    out2 = greedy_generate(params, cfg, prompt, steps=4, max_len=16)
+    out1, logits1 = greedy_generate(params, cfg, prompt, steps=4, max_len=16)
+    out2, logits2 = greedy_generate(params, cfg, prompt, steps=4, max_len=16)
     assert out1.shape == (2, 9)
+    assert logits1.shape == (2, 8, cfg.vocab_size)
     np.testing.assert_array_equal(out1, out2)
+    np.testing.assert_array_equal(logits1, logits2)
     np.testing.assert_array_equal(out1[:, :5], prompt)
+    # each new token is the argmax of the logits at the position before it
+    np.testing.assert_array_equal(out1[:, 5:], np.argmax(logits1[:, 4:], -1))
 
 
 def test_sliding_window_cache_is_bounded():

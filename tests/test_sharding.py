@@ -1,7 +1,7 @@
 """Logical-axis sharding rules: dedupe, divisibility fallback, GQA rules."""
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.distributed.sharding import (DEFAULT_RULES, _drop_nondividing,
                                         gqa_safe_rules, logical_spec,
@@ -22,15 +22,13 @@ def test_logical_spec_dedupes_mesh_axis():
 
 
 def test_drop_nondividing():
-    from repro.launch.mesh import make_abstract_mesh
-    mesh = make_abstract_mesh((2, 2), ("data", "model"))
+    mesh = AbstractMesh((2, 2), ("data", "model"))
     spec = _drop_nondividing(P("data", "model"), (10, 7), mesh)
     assert spec == P("data", None)    # 7 % 2 != 0
 
 
 def test_gqa_safe_rules():
-    from repro.launch.mesh import make_abstract_mesh
-    mesh = make_abstract_mesh((1, 4), ("data", "model"))
+    mesh = AbstractMesh((1, 4), ("data", "model"))
     rules = gqa_safe_rules(2, mesh)       # 2 kv heads % 4 != 0
     assert rules["kv_proj"] is None
     rules = gqa_safe_rules(4, mesh)
