@@ -36,7 +36,8 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 
-TRAIN_ARGS = ["--arch", "dlrm-mlp"]
+TRAIN_ARCH = "dlrm-mlp"
+TRAIN_SEED = 0
 TRAIN_BATCH = 8192
 TRAIN_STEPS = 30
 FOUR_CHIP_STEPS = 10
@@ -53,6 +54,16 @@ FLASH_TOL = 3e-2      # bf16 probabilities in the p @ v product
 #: |loss(4 chips) - loss(1 chip)| / loss(1 chip), per step: the gradient's
 #: bf16 partial sums are reduced across chips in another order
 FOUR_CHIP_LOSS_RTOL = 1e-2
+#: the same for the gradient norm of step 0, taken from the same parameters
+#: and batch on both, so only the summation order differs (rel 4e-5 for a
+#: bf16 dlrm-mlp of 4 x 1024 on four CPU devices, where a gradient of one
+#: device's quarter of the batch alone moved it by 3e-2)
+FOUR_CHIP_GRAD_RTOL = 2e-3
+#: |params(4 chips) - params(1 chip)| / |params(1 chip) - params(init)|
+#: after the run.  AdamW's first update is lr * sign(gradient), so only the
+#: elements whose gradient sign the summation order flips differ; a state
+#: left unchanged scores exactly 1, a gradient of another batch about 1
+FOUR_CHIP_PARAM_RTOL = 0.5
 
 
 class SmokeFailure(Exception):
@@ -80,6 +91,19 @@ def bytes_in_use(devices) -> list:
     return [d.memory_stats()["bytes_in_use"] for d in devices]
 
 
+def on_host(params) -> list:
+    import jax
+    import numpy as np
+    return [np.asarray(x, np.float32) for x in jax.tree.leaves(params)]
+
+
+def distance(xs, ys) -> float:
+    """Euclidean distance between two lists of arrays."""
+    import numpy as np
+    return math.sqrt(sum(float(np.sum(np.square(x - y)))
+                         for x, y in zip(xs, ys)))
+
+
 def require_tpu():
     import jax
     dev = jax.devices()[0]
@@ -93,8 +117,9 @@ def train(steps: int, mesh: str = "1x1"):
     from repro.launch import train as train_launcher
     with tempfile.TemporaryDirectory(prefix="chip_smoke_ckpt_") as ckpt:
         result = train_launcher.run(train_launcher.parse_args(
-            TRAIN_ARGS + ["--batch", str(TRAIN_BATCH), "--steps", str(steps),
-                          "--mesh", mesh, "--ckpt-dir", ckpt]))
+            ["--arch", TRAIN_ARCH, "--seed", str(TRAIN_SEED),
+             "--batch", str(TRAIN_BATCH), "--steps", str(steps),
+             "--mesh", mesh, "--ckpt-dir", ckpt]))
     taken = [h["step"] for h in result.history]
     check(taken == list(range(steps)),
           f"train on {mesh}: took steps {taken}, asked for 0..{steps - 1}")
@@ -211,16 +236,36 @@ def phase_four_chips() -> None:
           f"four chips: the state sits on {sorted(d.id for d in placed)}")
     check(min(in_use) > 0.5 * max(in_use),
           f"four chips: bytes in use per device {in_use}")
+    dp_params, dp_grad0 = on_host(dp.state.params), dp.history[0]["grad_norm"]
     del dp
     one, one_losses = train(FOUR_CHIP_STEPS, mesh="1x1")
+    one_params = on_host(one.state.params)
+    one_grad0 = one.history[0]["grad_norm"]
     del one
+    from repro.configs import get_config
+    from repro.train.loop import init_params
+    init = on_host(init_params(jax.random.PRNGKey(TRAIN_SEED),
+                               get_config(TRAIN_ARCH)))
     worst = max(abs(x - y) / abs(y) for x, y in zip(dp_losses, one_losses))
+    grad_diff = abs(dp_grad0 - one_grad0) / one_grad0
+    param_diff = (distance(dp_params, one_params)
+                  / distance(one_params, init))
     say(f"four-chips losses 4x1 {dp_losses}")
     say(f"four-chips losses 1x1 {one_losses}")
     say(f"four-chips per-step loss agreement: max rel diff {worst} "
         f"tol={FOUR_CHIP_LOSS_RTOL}")
+    say(f"four-chips step-0 grad norm 4x1 {dp_grad0} 1x1 {one_grad0}: "
+        f"rel diff {grad_diff} tol={FOUR_CHIP_GRAD_RTOL}")
+    say(f"four-chips params after {FOUR_CHIP_STEPS} steps: "
+        f"|4x1 - 1x1| / |1x1 - init| = {param_diff} "
+        f"tol={FOUR_CHIP_PARAM_RTOL}")
     check(worst <= FOUR_CHIP_LOSS_RTOL,
           f"four chips: losses differ from one chip by {worst}")
+    check(grad_diff <= FOUR_CHIP_GRAD_RTOL,
+          f"four chips: step-0 gradient norm differs by {grad_diff}")
+    check(param_diff <= FOUR_CHIP_PARAM_RTOL,
+          f"four chips: parameters differ from one chip by {param_diff} "
+          f"of their change")
 
 
 def main(argv=None) -> int:

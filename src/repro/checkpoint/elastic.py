@@ -16,31 +16,17 @@ from __future__ import annotations
 
 from typing import Any, Optional, Tuple
 
-import jax
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import Mesh
 
 from repro.data.pipeline import DataConfig
-from repro.distributed.sharding import (_drop_nondividing, logical_spec,
-                                        use_sharding)
-
-
-def reshard_specs(specs: Any, like: Any, mesh: Mesh, rules=None) -> Any:
-    """Logical specs + target mesh -> NamedSharding pytree (divisibility-safe)."""
-
-    def one(proto, axes):
-        spec = _drop_nondividing(logical_spec(axes, rules), proto.shape, mesh)
-        return NamedSharding(mesh, spec)
-
-    return jax.tree.map(
-        one, like, specs,
-        is_leaf=lambda x: hasattr(x, "shape") and hasattr(x, "dtype"))
+from repro.distributed.sharding import specs_to_shardings, use_sharding
 
 
 def restore_on_mesh(checkpointer, like: Any, specs: Any, mesh: Mesh,
                     rules=None, step: Optional[int] = None) -> Tuple[Any, int]:
     """Restore a checkpoint saved on any topology onto ``mesh``."""
     with use_sharding(mesh, rules):
-        shardings = reshard_specs(specs, like, mesh, rules=None)
+        shardings = specs_to_shardings(specs, like, mesh)
         return checkpointer.restore(like, step=step, shardings=shardings)
 
 

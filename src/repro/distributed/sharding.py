@@ -145,16 +145,22 @@ def is_logical_axes(x: Any) -> bool:
         isinstance(a, (str, type(None))) for a in x)
 
 
-def specs_to_shardings(specs: Any, mesh: Mesh,
+def specs_to_shardings(specs: Any, like: Any, mesh: Mesh,
                        rules: Optional[Rules] = None) -> Any:
-    """Map a pytree of logical-axis tuples to NamedShardings."""
-    binding = _active()
-    rules = rules or (binding[1] if binding else DEFAULT_RULES)
+    """NamedShardings for the arrays of ``like`` from their logical axes.
 
-    def to_sharding(axes):
-        return NamedSharding(mesh, logical_spec(axes, rules))
+    ``like`` holds arrays or ShapeDtypeStructs, ``specs`` a logical-axis
+    tuple at each of their places.  ``rules`` default to the active
+    binding's.  As in ``shard_hint``, a mesh axis that doesn't divide a
+    dimension is dropped (that dimension replicated): odd vocab sizes, 60
+    experts and batches the data axis doesn't divide are the norm.
+    """
 
-    return jax.tree.map(to_sharding, specs, is_leaf=is_logical_axes)
+    def to_sharding(leaf, axes):
+        spec = _drop_nondividing(logical_spec(axes, rules), leaf.shape, mesh)
+        return NamedSharding(mesh, spec)
+
+    return jax.tree.map(to_sharding, like, specs)
 
 
 def validate_divisibility(shapes: Any, shardings: Any) -> None:

@@ -58,15 +58,13 @@ def blocked_matmul(a: jnp.ndarray, b: jnp.ndarray,
                    bias: Optional[jnp.ndarray] = None,
                    act: Optional[str] = None,
                    block_m: int = 512, block_n: int = 512, block_k: int = 512,
-                   interpret: Optional[bool] = None) -> jnp.ndarray:
+                   interpret: bool = True) -> jnp.ndarray:
     """a (M, K) @ b (K, N) [+ bias (N,)] with fused activation.
 
     Requires M % block_m == K % block_k == N % block_n == 0 (the ops.py
-    wrapper pads).  ``interpret=None`` compiles the kernel when the default
-    backend is a TPU and interprets it on any other backend.
+    wrapper pads).  ``interpret=True`` runs the kernel body on CPU; on real
+    TPU pass interpret=False.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     if act is not None and act not in _ACTS:
         raise ValueError(f"unsupported activation {act}")
     M, K = a.shape

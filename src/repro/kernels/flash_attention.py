@@ -77,15 +77,13 @@ def flash_attention_bhsd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                          causal: bool = True, window: int = 0,
                          seq_len: Optional[int] = None,
                          block_q: int = 256, block_k: int = 512,
-                         interpret: Optional[bool] = None) -> jnp.ndarray:
+                         interpret: bool = True) -> jnp.ndarray:
     """q (B,H,Sp,dh), k/v (B,K,Sp,dh), Sp padded to block multiples.
 
-    ``seq_len`` = true (unpadded) length for key masking.  ``interpret=None``
-    compiles the kernel when the default backend is a TPU and interprets it
-    on any other backend.
+    ``seq_len`` = true (unpadded) length for key masking.
+    ``interpret=True`` runs the kernel body on CPU; on a TPU pass
+    interpret=False.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     B, H, Sp, dh = q.shape
     K = k.shape[1]
     G = H // K

@@ -26,6 +26,12 @@ from repro.kernels.flash_attention import flash_attention_bhsd
 _MIN_DIM = 256  # below this, kernel launch overhead > any win: use jnp
 
 
+def _interpret() -> bool:
+    """Compile the kernels on a TPU, interpret them anywhere else.  Asked
+    when a wrapper is traced, never at import."""
+    return jax.default_backend() != "tpu"
+
+
 def _pad_to(x: jnp.ndarray, mult: int, axis: int) -> jnp.ndarray:
     size = x.shape[axis]
     pad = (-size) % mult
@@ -53,7 +59,8 @@ def matmul(a: jnp.ndarray, b: jnp.ndarray,
     b2 = _pad_to(_pad_to(b, block, 0), block, 1)
     bias2 = _pad_to(bias, block, 0) if bias is not None else None
     y = blocked_matmul(a2, b2, bias=bias2, act=act,
-                       block_m=block, block_n=block, block_k=block)
+                       block_m=block, block_n=block, block_k=block,
+                       interpret=_interpret())
     return y[:M, :N].reshape(*lead, N)
 
 
@@ -70,5 +77,6 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     vt = _pad_to(jnp.swapaxes(v, 1, 2), 512, 2)
     bq, bk = 256, 512
     o = flash_attention_bhsd(qt, kt, vt, causal=causal, window=window,
-                             seq_len=S, block_q=bq, block_k=bk)
+                             seq_len=S, block_q=bq, block_k=bk,
+                             interpret=_interpret())
     return jnp.swapaxes(o[:, :, :S], 1, 2)

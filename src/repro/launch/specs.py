@@ -16,10 +16,10 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import Mesh
 
 from repro.configs.shapes import ShapeSpec
-from repro.distributed.sharding import is_logical_axes, logical_spec
+from repro.distributed.sharding import is_logical_axes, specs_to_shardings
 from repro.models.common import ModelConfig
 from repro.serve import engine as serve_engine
 from repro.train.loop import (TrainState, init_params, init_train_state,
@@ -27,12 +27,8 @@ from repro.train.loop import (TrainState, init_params, init_train_state,
 
 
 def _sds(shape, dtype, mesh: Optional[Mesh], axes) -> jax.ShapeDtypeStruct:
-    if mesh is None:
-        return jax.ShapeDtypeStruct(shape, dtype)
-    from repro.distributed.sharding import _drop_nondividing
-    spec = _drop_nondividing(logical_spec(axes), shape, mesh)
-    return jax.ShapeDtypeStruct(
-        shape, dtype, sharding=NamedSharding(mesh, spec))
+    leaf = jax.ShapeDtypeStruct(shape, dtype)
+    return leaf if mesh is None else attach(leaf, axes, mesh)
 
 
 def input_specs(cfg: ModelConfig, shape: ShapeSpec,
@@ -96,16 +92,9 @@ def attach(tree_abs, specs, mesh: Mesh):
     sizes, 60-expert MoE, 9-head attention are the norm in the assigned
     configs; dropping to replication is the standard fallback).
     """
-    from repro.distributed.sharding import _drop_nondividing
-
-    def one(abs_leaf, axes):
-        spec = _drop_nondividing(logical_spec(axes), abs_leaf.shape, mesh)
-        return jax.ShapeDtypeStruct(
-            abs_leaf.shape, abs_leaf.dtype,
-            sharding=NamedSharding(mesh, spec))
-
-    return jax.tree.map(one, tree_abs, specs,
-                        is_leaf=is_logical_axes)
+    return jax.tree.map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        tree_abs, specs_to_shardings(specs, tree_abs, mesh))
 
 
 def _augment_data_axis(pspecs):
@@ -133,23 +122,30 @@ def _augment_data_axis(pspecs):
 
 
 def train_state_specs(cfg: ModelConfig, zero1: bool = True,
-                      fsdp: bool = False) -> TrainState:
-    """Logical-axis pytree matching TrainState (params + AdamW mu/nu).
+                      fsdp: bool = False, optimizer=None) -> TrainState:
+    """Logical-axis pytree matching TrainState (params + optimizer state).
 
+    ``optimizer`` (default AdamW) decides the state's fields: each one
+    shaped like the params (AdamW mu/nu, SGD momentum) takes their specs,
+    any other (step counters) is replicated.
     ``zero1`` (baseline default): optimizer moments additionally sharded
     over the DP axis — free memory, GSPMD turns the gradient all-reduce
     into reduce-scatter (+ all-gather of the final update).
     ``fsdp``: the parameters themselves also DP-sharded (ZeRO-3-style),
     needed for the biggest assigned archs on 16 GiB chips.
     """
-    from repro.optim.optimizer import AdamWState
+    from repro.optim.optimizer import AdamW
     pspecs = model_param_specs(cfg)
     popt = _augment_data_axis(pspecs) if (zero1 or fsdp) else pspecs
     pmain = _augment_data_axis(pspecs) if fsdp else pspecs
-    return TrainState(
-        params=pmain,
-        opt_state=AdamWState(step=(), mu=popt, nu=popt),
-        step=(), rng=(None,))
+    params_abs = abstract_params(cfg)
+    opt_abs = jax.eval_shape((optimizer or AdamW()).init, params_abs)
+    like_params = jax.tree.structure(params_abs)
+    opt_specs = type(opt_abs)(*(
+        popt if jax.tree.structure(field) == like_params
+        else jax.tree.map(lambda _: (), field) for field in opt_abs))
+    return TrainState(params=pmain, opt_state=opt_specs,
+                      step=(), rng=(None,))
 
 
 def cache_logical_specs(cfg: ModelConfig, cache_abs) -> Any:

@@ -1,6 +1,47 @@
 """The production launchers run end-to-end on CPU (reduced configs)."""
 
+import os
+import subprocess
+import sys
+import textwrap
+from pathlib import Path
+
 import pytest
+
+REPO = Path(__file__).resolve().parent.parent
+
+# Runs in its own process: the CPU device count is fixed when JAX starts.
+_MESH_PROBE = textwrap.dedent("""
+    import os, sys
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    from repro.configs import get_config, get_reduced
+    from repro.launch import train as launcher
+    arch, mesh, batch, ckpt = sys.argv[1:]
+    # reduced widths with the published vocabulary (odd for whisper-tiny)
+    launcher.get_reduced = lambda a: get_reduced(a).replace(
+        vocab_size=get_config(a).vocab_size)
+    result = launcher.run(launcher.parse_args([
+        "--arch", arch, "--reduced", "--steps", "2", "--batch", batch,
+        "--seq", "16", "--mesh", mesh, "--ckpt-dir", ckpt]))
+    assert [h["step"] for h in result.history] == [0, 1], result.history
+    print("MESH_OK", result.history[-1]["loss"])
+""")
+
+
+@pytest.mark.parametrize("arch,mesh,batch", [
+    ("whisper-tiny", "1x2", "2"),    # vocab 51865 on a model axis of 2
+    ("dlrm-mlp", "2x1", "3"),        # batch 3 on a data axis of 2
+])
+def test_train_launcher_mesh_axis_not_dividing(tmp_path, arch, mesh, batch):
+    """A mesh axis that does not divide a dimension is dropped from its
+    sharding, in the state and in the batch, instead of failing the jit."""
+    probe = tmp_path / "probe.py"
+    probe.write_text(_MESH_PROBE)
+    env = dict(os.environ, PYTHONPATH=str(REPO / "src"), JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, str(probe), arch, mesh, batch, str(tmp_path / "ck")],
+        capture_output=True, text=True, env=env, timeout=300)
+    assert "MESH_OK" in out.stdout, out.stderr[-3000:]
 
 
 @pytest.mark.slow

@@ -66,3 +66,15 @@ def test_train_state_specs_zero1_adds_dp_shard():
         specs.opt_state.mu, is_leaf=lambda x: isinstance(x, tuple))
     assert not any("dp_shard" in t for t in flat_p if isinstance(t, tuple))
     assert any("dp_shard" in t for t in flat_m if isinstance(t, tuple))
+
+
+def test_train_state_specs_follow_the_optimizer_state():
+    from repro.configs import get_reduced
+    from repro.launch.specs import abstract_train_state, train_state_specs
+    from repro.optim.optimizer import SGD
+    cfg = get_reduced("smollm-135m")
+    specs = train_state_specs(cfg, zero1=False, optimizer=SGD())
+    assert specs.opt_state.step == ()
+    assert specs.opt_state.momentum == specs.params
+    # one spec at each array of the state SGD builds
+    jax.tree.map(lambda a, s: None, abstract_train_state(cfg, SGD()), specs)

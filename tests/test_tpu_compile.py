@@ -90,7 +90,8 @@ def _compile_dlrm_step(devices):
                  (DLRM_BATCH, cfg.mlp_widths[0]), jnp.float32),
              "click": jax.ShapeDtypeStruct((DLRM_BATCH,), jnp.float32)}
     with use_sharding(mesh, gqa_safe_rules(cfg.n_kv_heads, mesh)):
-        step, state_sh = jit_train_step(cfg, opt, TrainStepConfig(), mesh)
+        step, state_sh = jit_train_step(cfg, opt, TrainStepConfig(), mesh,
+                                        batch)
         state = jax.tree.map(lambda a, s: _sds(a.shape, a.dtype, s),
                              abstract_train_state(cfg, opt), state_sh)
         return step.lower(state, batch).compile()
