@@ -22,6 +22,7 @@ from typing import Dict, Iterator
 import numpy as np
 
 from repro.models.common import ModelConfig
+from repro.obs import trace
 
 
 @dataclasses.dataclass(frozen=True)
@@ -58,26 +59,28 @@ class SyntheticLMStream:
         self.trans = (p / p.sum(1, keepdims=True)).astype(np.float32)
 
     def batch(self, step: int) -> Dict[str, np.ndarray]:
-        d = self.data
-        rng = np.random.default_rng(
-            (d.seed * 1_000_003 + step) * 4096 + d.host_id)
-        B, S = d.host_batch, d.seq_len
-        toks = np.empty((B, S + 1), np.int32)
-        toks[:, 0] = rng.integers(0, self.v, B)
-        u = rng.random((B, S)).astype(np.float32)
-        cdf = np.cumsum(self.trans, axis=1)
-        for t in range(S):
-            toks[:, t + 1] = (
-                cdf[toks[:, t]] < u[:, t:t + 1]).sum(1).astype(np.int32)
-        batch = {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
-        if self.cfg.family == "encdec":
-            batch["frames"] = rng.standard_normal(
-                (B, self.cfg.encoder_seq, self.cfg.d_model)).astype(np.float32)
-        if self.cfg.family == "vlm":
-            batch["patches"] = rng.standard_normal(
-                (B, self.cfg.visual_tokens, self.cfg.visual_width)
-            ).astype(np.float32)
-        return batch
+        with trace.span("data.batch", step=step):
+            d = self.data
+            rng = np.random.default_rng(
+                (d.seed * 1_000_003 + step) * 4096 + d.host_id)
+            B, S = d.host_batch, d.seq_len
+            toks = np.empty((B, S + 1), np.int32)
+            toks[:, 0] = rng.integers(0, self.v, B)
+            u = rng.random((B, S)).astype(np.float32)
+            cdf = np.cumsum(self.trans, axis=1)
+            for t in range(S):
+                toks[:, t + 1] = (
+                    cdf[toks[:, t]] < u[:, t:t + 1]).sum(1).astype(np.int32)
+            batch = {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
+            if self.cfg.family == "encdec":
+                batch["frames"] = rng.standard_normal(
+                    (B, self.cfg.encoder_seq, self.cfg.d_model)
+                ).astype(np.float32)
+            if self.cfg.family == "vlm":
+                batch["patches"] = rng.standard_normal(
+                    (B, self.cfg.visual_tokens, self.cfg.visual_width)
+                ).astype(np.float32)
+            return batch
 
     def __iter__(self) -> Iterator[Dict[str, np.ndarray]]:
         step = 0
@@ -97,13 +100,15 @@ class SyntheticCTRStream:
                   ).astype(np.float32)
 
     def batch(self, step: int) -> Dict[str, np.ndarray]:
-        d = self.data
-        rng = np.random.default_rng(
-            (d.seed * 1_000_003 + step) * 4096 + d.host_id)
-        x = rng.standard_normal((d.host_batch, self.d_in)).astype(np.float32)
-        p = 1.0 / (1.0 + np.exp(-4.0 * x @ self.w))
-        y = (rng.random(d.host_batch) < p).astype(np.float32)
-        return {"features": x, "click": y}
+        with trace.span("data.batch", step=step):
+            d = self.data
+            rng = np.random.default_rng(
+                (d.seed * 1_000_003 + step) * 4096 + d.host_id)
+            x = rng.standard_normal((d.host_batch, self.d_in)
+                                    ).astype(np.float32)
+            p = 1.0 / (1.0 + np.exp(-4.0 * x @ self.w))
+            y = (rng.random(d.host_batch) < p).astype(np.float32)
+            return {"features": x, "click": y}
 
 
 def make_stream(cfg: ModelConfig, data: DataConfig):

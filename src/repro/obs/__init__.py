@@ -7,7 +7,9 @@ planner is, including jax-free CLI paths):
     thread-safe counters, exporting Chrome-trace-event JSON that loads
     directly into Perfetto (``ui.perfetto.dev``) or ``chrome://tracing``.
     Off by default and engineered to stay near-free when off; enabled via
-    env ``REPRO_TRACE=/path.json`` or CLI ``--trace PATH``.
+    env ``REPRO_TRACE=/path.json`` or CLI ``--trace PATH``.  While JAX's
+    profiler is collecting, every span also lands in its trace as a
+    ``jax.profiler.TraceAnnotation``, on the device ops' clock.
   * :mod:`repro.obs.metrics` — a process-wide registry of counters,
     gauges and histograms with JSON snapshot export, plus run-provenance
     capture (git sha, library versions, hostname, wall clock) stamped

@@ -18,15 +18,26 @@ is how the trace viewers render flame graphs — so the tracer keeps no
 explicit parent pointers and stays a flat, lock-guarded event list
 (thread-safe by construction; each event carries its thread id).
 
+**On the profiler's clock.**  While JAX's profiler is collecting
+(``jax.profiler.start_trace`` … ``stop_trace``), every span is also a
+``jax.profiler.TraceAnnotation``, whether or not a tracer is installed: it
+lands in the profile's ``.xplane.pb`` beside the device's ops, on the same
+clock, so a device gap can be charged to the program span open meanwhile.
+JAX is looked up only if it is already imported, so this module stays
+importable without it.
+
 **Disabled is the default, and disabled is near-free.**  ``span()`` with no
-active tracer is one module-global load plus returning a shared no-op
-context manager — no clock reads, no allocation beyond the kwargs dict —
-so instrumentation stays compiled into every hot path permanently
+active tracer and the profiler off is a module-global load, a lookup of
+``jax.profiler`` in ``sys.modules`` (with one ``is_enabled`` call once JAX
+is imported), and a shared no-op context manager — no clock reads, no
+allocation beyond the kwargs dict — so instrumentation stays compiled
+into every hot path permanently
 (``tests/test_obs.py`` pins the disabled-path overhead, and the committed
 ``planner_grid_candidates_per_s`` BENCH pin runs with these spans in
-place).  Enable with env ``REPRO_TRACE=/path/trace.json`` (written at
-process exit) or programmatically ``trace.enable(path)`` + ``write()``
-(what CLI ``--trace PATH`` does).
+place).  Enable the Chrome-JSON tracer with env
+``REPRO_TRACE=/path/trace.json`` (written at process exit) or
+programmatically ``trace.enable(path)`` + ``write()`` (what CLI
+``--trace PATH`` does).
 
 :func:`validate_chrome_trace` is the schema gate CI runs on emitted
 artifacts: top-level shape, per-event required fields, non-negative
@@ -37,6 +48,7 @@ from __future__ import annotations
 import atexit
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional, Union
@@ -70,27 +82,39 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """One live ``"X"`` (complete) event; records on ``__exit__``."""
+    """One live ``"X"`` (complete) event; records on ``__exit__`` into the
+    tracer, if there is one, and into the profiler's trace through
+    ``annotation``, a ``jax.profiler.TraceAnnotation``, if it is given."""
 
-    __slots__ = ("_tracer", "name", "args", "_start_ns")
+    __slots__ = ("_tracer", "name", "args", "_start_ns", "_annotation")
 
-    def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
+    def __init__(self, tracer: Optional["Tracer"], name: str,
+                 args: Dict[str, Any], annotation: Any = None):
         self._tracer = tracer
         self.name = name
         self.args = args
+        self._annotation = annotation
 
     def __enter__(self) -> "_Span":
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._start_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
         end_ns = time.perf_counter_ns()
-        self._tracer._record(self.name, self._start_ns, end_ns, self.args)
+        if self._tracer is not None:
+            self._tracer._record(self.name, self._start_ns, end_ns,
+                                 self.args)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         return False
 
     def set(self, **args) -> "_Span":
         """Attach args discovered while the span is open (counts, sizes)."""
         self.args.update(args)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**args)
         return self
 
 
@@ -215,9 +239,23 @@ def active() -> Optional[Tracer]:
     return _TRACER
 
 
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` while JAX's profiler is collecting,
+    else None (and None while JAX is not imported: nothing can be
+    collecting then)."""
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is not None and profiler.TraceAnnotation.is_enabled():
+        return profiler.TraceAnnotation
+    return None
+
+
 def span(name: str, **args):
-    """A context-manager span under the process tracer (no-op when disabled)."""
+    """A context-manager span under the process tracer and, while JAX's
+    profiler is collecting, in its trace (a no-op when neither is on)."""
     t = _TRACER
+    annotation = _profiler_annotation()
+    if annotation is not None:
+        return _Span(t, name, args, annotation(name, **args))
     if t is None:
         return _NULL_SPAN
     return _Span(t, name, args)

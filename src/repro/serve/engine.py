@@ -8,6 +8,7 @@ sampling helper included for the runnable demos.
 """
 from __future__ import annotations
 
+import itertools
 from typing import Callable, Tuple
 
 import jax
@@ -24,16 +25,17 @@ from repro.measure.timers import block_until_ready
 
 def build_serve_step(cfg: ModelConfig) -> Callable:
     if cfg.family == "encdec":
-        def serve_step(params, tokens, cache, pos):
-            logits, cache = encdec_mod.decode_step(params, tokens, cache,
-                                                   pos, cfg)
-            return logits, cache
+        decode = encdec_mod.decode_step
     elif cfg.family == "vlm":
-        def serve_step(params, tokens, cache, pos):
-            return vlm_mod.decode_step(params, tokens, cache, pos, cfg)
+        decode = vlm_mod.decode_step
     else:
-        def serve_step(params, tokens, cache, pos):
-            return lm_mod.decode_step(params, tokens, cache, pos, cfg)
+        decode = lm_mod.decode_step
+
+    def serve_step(params, tokens, cache, pos):
+        # under jax.jit this body runs only while JAX traces it, so each
+        # span marks one retrace and lasts as long as the Python trace
+        with trace.span("serve.trace_step"):
+            return decode(params, tokens, cache, pos, cfg)
     return serve_step
 
 
@@ -47,6 +49,10 @@ def init_cache(params, cfg: ModelConfig, batch: int, max_len: int,
     return lm_mod.init_cache(cfg, batch, max_len)
 
 
+#: numbers the ``serve.generate`` spans of this process
+_CALLS = itertools.count(1)
+
+
 def greedy_generate(params, cfg: ModelConfig, prompt: jnp.ndarray,
                     steps: int, max_len: int,
                     frames: jnp.ndarray | None = None
@@ -55,26 +61,43 @@ def greedy_generate(params, cfg: ModelConfig, prompt: jnp.ndarray,
 
     Returns the tokens (B, S + steps) and the decode logits
     (B, S + steps - 1, V), whose column t was computed at position t.
+
+    Spans (``repro.obs.trace``): ``serve.generate`` over the whole call;
+    ``serve.prefill`` over positions 0..S-1, ending when the first new
+    token's logits are ready; ``serve.decode`` over the rest; in each,
+    one ``serve.step`` per position (dispatch and sync, what the
+    ``serve.step_seconds`` histogram times) holding a ``serve.sync``.
     """
     B, S = prompt.shape
-    serve_step = jax.jit(build_serve_step(cfg))
-    cache = init_cache(params, cfg, B, max_len, frames=frames)
-    tok = prompt[:, :1]
-    out = [tok]
-    seen = []
-    step_hist = REGISTRY.histogram("serve.step_seconds")
-    with trace.span("serve.generate", arch=cfg.name, batch=B,
+    with trace.span("serve.generate", call=next(_CALLS), batch=B,
                     prompt_len=S, steps=steps):
-        for t in range(S + steps - 1):
+        serve_step = jax.jit(build_serve_step(cfg))
+        cache = init_cache(params, cfg, B, max_len, frames=frames)
+        out = [prompt[:, :1]]
+        seen = []
+        step_hist = REGISTRY.histogram("serve.step_seconds")
+
+        def advance(t, cache):
             # per-token decode latency: block inside the timed region so
             # async dispatch is charged for the work, not the dispatch
-            with step_hist.time():
-                logits, cache = serve_step(params, tok, cache, jnp.int32(t))
-                block_until_ready(logits)
+            with trace.span("serve.step"), step_hist.time():
+                logits, cache = serve_step(params, out[-1], cache,
+                                           jnp.int32(t))
+                with trace.span("serve.sync"):
+                    block_until_ready(logits)
             seen.append(logits[:, -1])
             if t + 1 < S:
-                tok = prompt[:, t + 1:t + 2]
+                out.append(prompt[:, t + 1:t + 2])
             else:
-                tok = jnp.argmax(logits[:, -1:], axis=-1).astype(prompt.dtype)
-            out.append(tok)
-    return jnp.concatenate(out, axis=1), jnp.stack(seen, axis=1)
+                out.append(jnp.argmax(logits[:, -1:], axis=-1)
+                           .astype(prompt.dtype))
+            return cache
+
+        positions = steps + prompt.shape[1] - 1
+        with trace.span("serve.prefill"):
+            for t in range(min(S, positions)):
+                cache = advance(t, cache)
+        with trace.span("serve.decode"):
+            for t in range(S, positions):
+                cache = advance(t, cache)
+        return jnp.concatenate(out, axis=1), jnp.stack(seen, axis=1)

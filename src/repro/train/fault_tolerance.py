@@ -33,7 +33,6 @@ import jax
 
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.obs import trace
-from repro.obs.metrics import REGISTRY
 
 
 @dataclasses.dataclass
@@ -105,27 +104,26 @@ class ResilientRunner:
         step = start_step
         retries = 0
         last_failed_step = -1
-        step_hist = REGISTRY.histogram("train.step_seconds")
         with trace.span("train.run", n_steps=n_steps,
                         start_step=start_step) as run_sp:
             while step < n_steps:
                 try:
-                    t0 = time.monotonic()
-                    if self.failure_hook is not None:
-                        self.failure_hook(step)   # inside the timed window
-                    batch = stream.batch(step)
-                    state, metrics = self.train_step(state, batch)
-                    jax.block_until_ready(metrics["loss"])
-                    dt = time.monotonic() - t0
-                    step_hist.observe(dt)
+                    with trace.span("train.step", step=step):
+                        t0 = time.monotonic()
+                        if self.failure_hook is not None:
+                            self.failure_hook(step)  # inside the timed window
+                        batch = stream.batch(step)
+                        state, metrics = self.train_step(state, batch)
+                        with trace.span("train.sync"):
+                            jax.block_until_ready(metrics["loss"])
+                        dt = time.monotonic() - t0
                     self._track_time(step, dt)
                     history.append(
                         {k: float(v) for k, v in metrics.items()}
                         | {"step": step, "seconds": dt})
                     step += 1
                     if step % self.cfg.ckpt_every == 0:
-                        self.ckpt.save(step, state,
-                                       async_=self.cfg.async_ckpt)
+                        self._save(step, state, self.cfg.async_ckpt)
                 except _RECOVERABLE as e:  # noqa: PERF203
                     # retries are counted PER FAILING STEP: a replay that
                     # makes progress and then fails at the same step again
@@ -148,8 +146,12 @@ class ResilientRunner:
                 run_sp.set(steps_run=len(history),
                            n_stragglers=len(self.stragglers))
         self.ckpt.wait()
-        self.ckpt.save(n_steps, state, async_=False)
+        self._save(n_steps, state, False)
         return state, history
+
+    def _save(self, step: int, state, async_: bool) -> None:
+        with trace.span("train.checkpoint", step=step, async_=async_):
+            self.ckpt.save(step, state, async_=async_)
 
     def _track_time(self, step: int, dt: float) -> None:
         # the first measured step carries jit compilation — seeding the EWMA

@@ -81,9 +81,15 @@ def build_train_step(cfg: ModelConfig, optimizer,
                      ts_cfg: TrainStepConfig = TrainStepConfig()):
     loss_fn = make_loss_fn(cfg)
 
+    def forward(params, batch):
+        # the scope names the forward ops in the HLO's metadata; their
+        # gradients carry transpose(jvp(train.forward))
+        with jax.named_scope("train.forward"):
+            return loss_fn(params, batch)
+
     def grads_of(params, batch):
         (loss, metrics), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params, batch)
+            forward, has_aux=True)(params, batch)
         return loss, metrics, grads
 
     def train_step(state: TrainState, batch: Dict[str, jnp.ndarray]
@@ -113,9 +119,11 @@ def build_train_step(cfg: ModelConfig, optimizer,
         if ts_cfg.compression is not None:
             grads = ts_cfg.compression.round_trip(grads)
 
-        updates, opt_state = optimizer.update(grads, state.opt_state, params)
         from repro.optim.optimizer import apply_updates, global_norm
-        params = apply_updates(params, updates)
+        with jax.named_scope("train.optimizer"):
+            updates, opt_state = optimizer.update(grads, state.opt_state,
+                                                  params)
+            params = apply_updates(params, updates)
         metrics = dict(metrics, loss=loss, grad_norm=global_norm(grads),
                        step=state.step)
         new_state = TrainState(params=params, opt_state=opt_state,

@@ -93,6 +93,63 @@ def test_disabled_module_span_is_shared_noop():
     assert trace.write() is None
 
 
+def _host_event_names(profile_dir):
+    import glob
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(str(profile_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    return [e.name for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:") for line in plane.lines
+            for e in line.events]
+
+
+def test_span_lands_in_the_profilers_trace(tmp_path):
+    import jax
+    assert not trace.enabled()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with trace.span("obs.profiled", n=3) as sp:
+            assert sp is not trace._NULL_SPAN
+            sp.set(found=1)
+    finally:
+        jax.profiler.stop_trace()
+    assert trace.span("obs.after") is trace._NULL_SPAN
+    names = [n.split("#", 1)[0] for n in _host_event_names(tmp_path)]
+    assert "obs.profiled" in names and "obs.after" not in names
+
+
+def test_profiled_span_still_records_into_the_tracer(tmp_path):
+    import jax
+    try:
+        t = trace.enable()
+        jax.profiler.start_trace(str(tmp_path / "prof"))
+        try:
+            with trace.span("obs.both"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        assert [e["name"] for e in t.to_dict()["traceEvents"]] == [
+            "obs.both"]
+    finally:
+        trace.disable()
+    names = [n.split("#", 1)[0] for n in
+             _host_event_names(tmp_path / "prof")]
+    assert "obs.both" in names
+
+
+def test_obs_imports_and_spans_without_jax():
+    import subprocess
+    import sys
+    code = ("import sys; sys.modules['jax'] = None\n"
+            "from repro.obs import trace\n"
+            "assert trace.span('x') is trace._NULL_SPAN\n"
+            "assert 'jax.profiler' not in sys.modules")
+    import repro
+    src = os.path.dirname(os.path.dirname(repro.__file__))
+    subprocess.run([sys.executable, "-c", code], check=True,
+                   env=dict(os.environ, PYTHONPATH=src, REPRO_TRACE=""))
+
+
 def test_enable_disable_module_tracer(tmp_path):
     try:
         t = trace.enable(str(tmp_path / "m.json"))

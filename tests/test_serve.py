@@ -99,3 +99,36 @@ def test_sliding_window_cache_is_bounded():
             assert row["k"].shape[1] == 4096
         else:
             assert row["k"].shape[1] == cfg.sliding_window
+
+
+def test_greedy_generate_spans_under_the_profiler(tmp_path):
+    import glob
+    from jax.profiler import ProfileData
+    cfg = get_reduced("smollm-135m").replace(compute_dtype=jnp.float32)
+    params = _params(cfg)
+    S, G = 5, 4
+    prompt = jax.random.randint(KEY, (2, S), 0, cfg.vocab_size)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        greedy_generate(params, cfg, prompt, G, S + G)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = [(e.name.split("#", 1)[0], e.start_ns, e.end_ns)
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:") for line in plane.lines
+             for e in line.events if e.name.startswith("serve.")]
+
+    def inside(outer, name):
+        return [s for s in spans if s[0] == name and outer[1] <= s[1]
+                and s[2] <= outer[2]]
+
+    gen, = [s for s in spans if s[0] == "serve.generate"]
+    prefill, = inside(gen, "serve.prefill")
+    decode, = inside(gen, "serve.decode")
+    assert prefill[2] <= decode[1]
+    assert len(inside(prefill, "serve.step")) == S
+    assert len(inside(decode, "serve.step")) == G - 1
+    steps = inside(gen, "serve.step")
+    assert len(steps) == S + G - 1
+    assert all(len(inside(step, "serve.sync")) == 1 for step in steps)
