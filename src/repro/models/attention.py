@@ -1,4 +1,5 @@
-"""Grouped-query attention: init, full-sequence apply, single-token decode.
+"""Grouped-query attention: init, full-sequence apply, whole-prompt prefill
+and single-token decode into a KV cache.
 
 Supports: GQA (kv heads < q heads), optional QKV bias (Qwen2), per-head QK
 RMS-norm (Qwen3), RoPE / learned / no positions, causal or bidirectional,
@@ -268,6 +269,38 @@ def decode_attention(
     out = _sdpa_grouped(q, k, v, bias, cfg)
     out = out.reshape(B, 1, cfg.q_dim) @ p["wo"].astype(dt)
     return out, {"k": k, "v": v}
+
+
+def prefill_attention(
+    p: Params,
+    x: jnp.ndarray,                 # (B, S, D) the prompt's activations
+    layer_cache: Dict[str, jnp.ndarray],   # k/v (B, S_max, K, dh) this layer
+    cfg: ModelConfig,
+    *,
+    window: int = 0,
+) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """Whole-prompt attention from position 0: causal over the S new keys,
+    whose rope'd K/V are written to cache rows [0, S)."""
+    dt = cfg.compute_dtype
+    x = x.astype(dt)
+    B, S = x.shape[0], x.shape[1]
+    q, k, v = _project_qkv(p, x, x, cfg)
+    if cfg.pos_emb == "rope":
+        positions = jnp.arange(S)[None, :]
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+    # the write is at the static offset 0, so it is the same elementwise
+    # select on the seq axis as decode_attention's and partitions as
+    # trivially when kv_seq is sharded over the mesh
+    S_max = layer_cache["k"].shape[1]
+    fresh = (jnp.arange(S_max) < S)[None, :, None, None]
+    rows = ((0, 0), (0, S_max - S), (0, 0), (0, 0))
+    new = {n: jnp.where(fresh, jnp.pad(t.astype(layer_cache[n].dtype), rows),
+                        layer_cache[n])
+           for n, t in (("k", k), ("v", v))}
+    out = _sdpa_grouped(q, k, v, _mask_bias(S, S, True, window), cfg)
+    out = out.reshape(B, S, cfg.q_dim) @ p["wo"].astype(dt)
+    return out, new
 
 
 def _sdpa_grouped(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,

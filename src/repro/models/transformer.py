@@ -12,7 +12,8 @@ production layout).  xLSTM is shallow and heterogeneous -> unrolled.
 
 ``forward`` returns (logits, aux) where aux is the MoE load-balance loss
 (0 for non-MoE).  ``decode_step`` performs one-token decode against the
-cache pytree built by ``init_cache``.
+cache pytree built by ``init_cache``; ``prefill`` fills a dense model's
+cache from a whole prompt in one pass.
 """
 from __future__ import annotations
 
@@ -141,6 +142,12 @@ def _apply_dense_block(blk: Params, x: jnp.ndarray, cfg: ModelConfig
         a = shard_hint(a, ("batch", "seq", "embed"))
     x = x + a
     x = shard_hint(x, ("batch", "seq", "embed"))
+    return _apply_ffn_residual(blk, x, cfg)
+
+
+def _apply_ffn_residual(blk: Params, x: jnp.ndarray, cfg: ModelConfig
+                        ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """A dense block's second half: norm, FFN (or MoE) and the residual."""
     h = apply_norm(blk["ffn_norm"], x, cfg)
     if "moe" in blk:
         out, aux = moe_mod.apply_moe(blk["moe"], h, cfg)
@@ -236,6 +243,41 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Dict[str, Any]:
     if cfg.family == "hybrid":
         return hybrid_mod.init_hymba_cache(cfg, batch, max_len)
     return attn_mod.init_kv_cache(cfg, batch, max_len)
+
+
+def prefill(params: Params, tokens: jnp.ndarray, cache: Dict[str, Any],
+            cfg: ModelConfig) -> Tuple[jnp.ndarray, Dict[str, Any]]:
+    """tokens (B, S) + an empty KV cache -> (logits (B, S, V), the cache
+    with rows [0, S) written): the whole prompt in one causal pass, the
+    same numbers as S ``decode_step`` calls at positions 0..S-1.  Dense
+    family only: the others carry recurrent state or route tokens."""
+    if cfg.family != "dense":
+        raise ValueError(f"prefill is for the dense family, not {cfg.family}")
+    x = _embed(params, tokens, cfg)
+    x = shard_hint(x, ("batch", "seq", "embed"))
+
+    def body(x, inp):
+        blk, krow, vrow = inp
+        h = apply_norm(blk["attn_norm"], x, cfg)
+        a, kv = attn_mod.prefill_attention(
+            blk["attn"], h, {"k": krow, "v": vrow}, cfg,
+            window=cfg.sliding_window)
+        x, _ = _apply_ffn_residual(blk, x + a, cfg)
+        return x, (kv["k"], kv["v"])
+
+    if cfg.scan_layers:
+        x, (k, v) = jax.lax.scan(
+            body, x, (params["blocks"], cache["k"], cache["v"]))
+    else:
+        kvs = []
+        for i, blk in enumerate(params["blocks"]):
+            x, kv = body(x, (blk, cache["k"][i], cache["v"][i]))
+            kvs.append(kv)
+        k, v = (jnp.stack(t) for t in zip(*kvs))
+
+    x = apply_norm(params["final_norm"], x, cfg)
+    head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
+    return x @ head.astype(cfg.compute_dtype), {"k": k, "v": v}
 
 
 def decode_step(params: Params, tokens: jnp.ndarray, cache: Dict[str, Any],

@@ -52,21 +52,29 @@ def init_cache(params, cfg: ModelConfig, batch: int, max_len: int,
 #: numbers the ``serve.generate`` spans of this process
 _CALLS = itertools.count(1)
 
+#: the dense model's whole-prompt prefill, one trace per config and shape
+_prefill = jax.jit(lm_mod.prefill, static_argnames="cfg")
+
 
 def greedy_generate(params, cfg: ModelConfig, prompt: jnp.ndarray,
                     steps: int, max_len: int,
                     frames: jnp.ndarray | None = None
                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Prefill token-by-token then greedy-decode ``steps`` tokens.
+    """Prefill the prompt, then greedy-decode ``steps`` tokens.
 
-    Returns the tokens (B, S + steps) and the decode logits
+    A dense model prefills the whole prompt in one call
+    (``transformer.prefill``); the other families carry recurrent state,
+    route tokens or decode through steps of their own, and prefill token
+    by token.  Returns the tokens (B, S + steps) and the logits
     (B, S + steps - 1, V), whose column t was computed at position t.
 
     Spans (``repro.obs.trace``): ``serve.generate`` over the whole call;
     ``serve.prefill`` over positions 0..S-1, ending when the first new
-    token's logits are ready; ``serve.decode`` over the rest; in each,
-    one ``serve.step`` per position (dispatch and sync, what the
-    ``serve.step_seconds`` histogram times) holding a ``serve.sync``.
+    token's logits are ready; ``serve.decode`` over the rest.  In each,
+    one ``serve.step`` per single-token decode call (dispatch and sync,
+    what the ``serve.step_seconds`` histogram times) holding a
+    ``serve.sync``; a batched prefill is one ``serve.prefill_step``
+    holding a ``serve.sync``, timed by ``serve.prefill_seconds``.
     """
     B, S = prompt.shape
     with trace.span("serve.generate", call=next(_CALLS), batch=B,
@@ -85,7 +93,7 @@ def greedy_generate(params, cfg: ModelConfig, prompt: jnp.ndarray,
                                            jnp.int32(t))
                 with trace.span("serve.sync"):
                     block_until_ready(logits)
-            seen.append(logits[:, -1])
+            seen.append(logits[:, -1:])
             if t + 1 < S:
                 out.append(prompt[:, t + 1:t + 2])
             else:
@@ -95,9 +103,21 @@ def greedy_generate(params, cfg: ModelConfig, prompt: jnp.ndarray,
 
         positions = steps + prompt.shape[1] - 1
         with trace.span("serve.prefill"):
-            for t in range(min(S, positions)):
-                cache = advance(t, cache)
+            if cfg.family == "dense":
+                with trace.span("serve.prefill_step"), REGISTRY.histogram(
+                        "serve.prefill_seconds").time():
+                    logits, cache = _prefill(params, prompt, cache, cfg=cfg)
+                    with trace.span("serve.sync"):
+                        block_until_ready(logits)
+                seen.append(logits[:, :positions])
+                out = [prompt]
+                if steps:
+                    out.append(jnp.argmax(logits[:, -1:], axis=-1)
+                               .astype(prompt.dtype))
+            else:
+                for t in range(min(S, positions)):
+                    cache = advance(t, cache)
         with trace.span("serve.decode"):
             for t in range(S, positions):
                 cache = advance(t, cache)
-        return jnp.concatenate(out, axis=1), jnp.stack(seen, axis=1)
+        return jnp.concatenate(out, axis=1), jnp.concatenate(seen, axis=1)

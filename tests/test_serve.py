@@ -127,8 +127,81 @@ def test_greedy_generate_spans_under_the_profiler(tmp_path):
     prefill, = inside(gen, "serve.prefill")
     decode, = inside(gen, "serve.decode")
     assert prefill[2] <= decode[1]
-    assert len(inside(prefill, "serve.step")) == S
+    # a dense model prefills the whole prompt in one call
+    batched, = inside(prefill, "serve.prefill_step")
+    assert len(inside(batched, "serve.sync")) == 1
+    assert not inside(prefill, "serve.step")
     assert len(inside(decode, "serve.step")) == G - 1
     steps = inside(gen, "serve.step")
-    assert len(steps) == S + G - 1
+    assert len(steps) == G - 1
     assert all(len(inside(step, "serve.sync")) == 1 for step in steps)
+
+
+def _with_qkv_bias(params, cfg):
+    """Non-zero q/k/v biases, so a prefill that dropped them would show."""
+    if not cfg.qkv_bias:
+        return params
+    attn = dict(params["blocks"]["attn"])
+    for i, b in enumerate(("bq", "bk", "bv")):
+        attn[b] = 0.1 * jax.random.normal(jax.random.fold_in(KEY, i),
+                                          attn[b].shape)
+    return {**params, "blocks": {**params["blocks"], "attn": attn}}
+
+
+def _token_loop(params, cfg, prompt, steps, max_len):
+    """Greedy generation fed one position at a time through
+    ``build_serve_step``: (tokens, logits (B, S + steps - 1, V), cache)."""
+    serve = jax.jit(build_serve_step(cfg))
+    cache = init_cache(params, cfg, prompt.shape[0], max_len)
+    S = prompt.shape[1]
+    tokens, logits = [prompt[:, :1]], []
+    for t in range(S + steps - 1):
+        lg, cache = serve(params, tokens[-1], cache, jnp.int32(t))
+        logits.append(lg[:, 0])
+        tokens.append(prompt[:, t + 1:t + 2] if t + 1 < S else
+                      jnp.argmax(lg, axis=-1).astype(prompt.dtype))
+    return jnp.concatenate(tokens, 1), jnp.stack(logits, 1), cache
+
+
+@pytest.mark.parametrize("arch", ["smollm-135m", "qwen2.5-3b"])
+def test_prefill_matches_the_decode_steps(arch):
+    cfg = get_reduced(arch).replace(compute_dtype=jnp.float32)
+    params = _with_qkv_bias(_params(cfg), cfg)
+    S, max_len = 6, 10
+    prompt = jax.random.randint(KEY, (2, S), 0, cfg.vocab_size)
+    _, want, want_cache = _token_loop(params, cfg, prompt, 1, max_len)
+    got, cache = jax.jit(lm_mod.prefill, static_argnames="cfg")(
+        params, prompt, init_cache(params, cfg, 2, max_len), cfg=cfg)
+    np.testing.assert_allclose(got, want, atol=2e-4, rtol=1e-3)
+    for n in ("k", "v"):
+        assert cache[n].shape == want_cache[n].shape
+        assert cache[n].dtype == want_cache[n].dtype
+        np.testing.assert_allclose(cache[n][:, :, :S], want_cache[n][:, :, :S],
+                                   atol=2e-4, rtol=1e-3)
+        assert not np.any(np.asarray(cache[n][:, :, S:]))
+
+
+def test_greedy_generate_matches_the_token_loop():
+    cfg = get_reduced("smollm-135m").replace(compute_dtype=jnp.float32)
+    params = _params(cfg)
+    S, G = 5, 4
+    prompt = jax.random.randint(KEY, (2, S), 0, cfg.vocab_size)
+    tokens, logits = greedy_generate(params, cfg, prompt, G, S + G)
+    want_tokens, want_logits, _ = _token_loop(params, cfg, prompt, G, S + G)
+    np.testing.assert_array_equal(tokens, want_tokens)
+    np.testing.assert_allclose(logits, want_logits, atol=2e-4, rtol=1e-3)
+
+
+@pytest.mark.parametrize("arch,prefills", [
+    ("smollm-135m", 1), ("hymba-1.5b", 0), ("xlstm-125m", 0),
+    ("qwen2-moe-a2.7b", 0)])
+def test_only_a_dense_model_takes_the_batched_prefill(arch, prefills):
+    from repro.obs.metrics import REGISTRY
+    cfg = get_reduced(arch).replace(compute_dtype=jnp.float32)
+    params = _params(cfg)
+    prompt = jax.random.randint(KEY, (2, 3), 0, cfg.vocab_size)
+    hist = REGISTRY.histogram("serve.prefill_seconds")
+    for _ in range(2):
+        before = hist.count
+        greedy_generate(params, cfg, prompt, 2, 5)
+        assert hist.count - before == prefills
