@@ -4,10 +4,12 @@ and single-token decode into a KV cache.
 Supports: GQA (kv heads < q heads), optional QKV bias (Qwen2), per-head QK
 RMS-norm (Qwen3), RoPE / learned / no positions, causal or bidirectional,
 sliding-window masks (Hymba local layers), cross-attention (Whisper decoder),
-and a Pallas flash-attention fast path (``cfg.use_flash``).
+and a Pallas flash-attention fast path (``cfg.use_flash``).  Multi-head
+latent attention (DeepSeek-V2/V3, ``cfg.kv_lora_rank``) is at the end.
 
 Shapes: activations (B, S, D); per-head tensors (B, S, H, dh).
-KV cache for decode: dict(k=(B, S_max, Hkv, dh), v=..., pos scalar index).
+KV cache for decode: dict(k=(B, S_max, Hkv, dh), v=..., pos scalar index);
+a latent cache: dict(c_kv=(B, S_max, C), k_pe=(B, S_max, dr)).
 """
 from __future__ import annotations
 
@@ -327,3 +329,154 @@ def _sdpa_grouped(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     w = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgqs,bskd->bqkgd", w, v)
     return out.reshape(B, Sq, H, dh)
+
+
+# --- multi-head latent attention (MLA) ----------------------------------------
+# K and V come from a latent c_kv = RMSNorm(x W_kv_a[:, :C]) of C values a
+# token, expanded per head by W_kv_b into k_nope (dn) and v (dv); a shared
+# rope'd key k_pe (dr) comes from the rest of W_kv_a.  Queries have no
+# low-rank step: x W_q gives per head q_nope (dn) and q_pe (dr), and the
+# scores are q_nope.k_nope + q_pe.k_pe over sqrt(dn + dr).  The cache holds
+# only c_kv and k_pe, C + dr values a token a layer.
+
+def init_mla(key, cfg: ModelConfig) -> Params:
+    H, C = cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 4)
+    return {"wq": dense_init(ks[0], cfg.d_model, H * (dn + dr)),
+            "wkv_a": dense_init(ks[1], cfg.d_model, C + dr),
+            "kv_norm": ones((C,)),
+            "wkv_b": dense_init(ks[2], C, H * (dn + dv)),
+            "wo": dense_init(ks[3], H * dv, cfg.d_model)}
+
+
+def mla_specs(cfg: ModelConfig) -> Specs:
+    return {"wq": ("embed", "q_proj"), "wkv_a": ("embed", None),
+            "kv_norm": (None,), "wkv_b": (None, "q_proj"),
+            "wo": ("q_proj", "embed")}
+
+
+def init_latent_cache(cfg: ModelConfig, batch: int, max_len: int,
+                      n_layers: int, dtype=None) -> Dict[str, jnp.ndarray]:
+    dt = dtype or cfg.compute_dtype
+    return {"c_kv": jnp.zeros((n_layers, batch, max_len, cfg.kv_lora_rank), dt),
+            "k_pe": jnp.zeros((n_layers, batch, max_len, cfg.qk_rope_head_dim),
+                              dt)}
+
+
+def latent_cache_specs() -> Specs:
+    return {"c_kv": ("layers", "batch", "kv_seq", None),
+            "k_pe": ("layers", "batch", "kv_seq", None)}
+
+
+def _mla_project(p: Params, x: jnp.ndarray, positions: jnp.ndarray,
+                 cfg: ModelConfig):
+    """(q_nope, rope'd q_pe) per head, the normalised latent c_kv and the
+    rope'd shared key k_pe, for the tokens of x at ``positions``."""
+    dt = cfg.compute_dtype
+    B, S = x.shape[0], x.shape[1]
+    C, dn = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    q = (x @ p["wq"].astype(dt)).reshape(B, S, cfg.n_heads, -1)
+    q_pe = apply_rope(q[..., dn:], positions, cfg.rope_theta)
+    kv = x @ p["wkv_a"].astype(dt)
+    c_kv = rms_norm_head(kv[..., :C], p["kv_norm"], cfg.norm_eps)
+    k_pe = apply_rope(kv[:, :, None, C:], positions, cfg.rope_theta)[:, :, 0]
+    return q[..., :dn], q_pe, c_kv, k_pe
+
+
+def _mla_softmax(s_nope: jnp.ndarray, s_pe: jnp.ndarray, bias, cfg: ModelConfig
+                 ) -> jnp.ndarray:
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    scores = (s_nope + s_pe) * scale
+    if bias is not None:
+        scores = scores + bias
+    return jax.nn.softmax(scores, axis=-1).astype(cfg.compute_dtype)
+
+
+def _mla_expanded(p: Params, q_nope, q_pe, c_kv, k_pe, bias,
+                  cfg: ModelConfig) -> jnp.ndarray:
+    """Attention with K and V expanded per head from the latent."""
+    dt, f32 = cfg.compute_dtype, jnp.float32
+    B, Sq, H = q_nope.shape[:3]
+    dn = cfg.qk_nope_head_dim
+    kv = (c_kv @ p["wkv_b"].astype(dt)).reshape(B, c_kv.shape[1], H, -1)
+    k_nope, v = kv[..., :dn], kv[..., dn:]
+    w = _mla_softmax(
+        jnp.einsum("bqhd,bshd->bhqs", q_nope, k_nope, preferred_element_type=f32),
+        jnp.einsum("bqhr,bsr->bhqs", q_pe, k_pe, preferred_element_type=f32),
+        bias, cfg)
+    out = jnp.einsum("bhqs,bshd->bqhd", w, v).reshape(B, Sq, -1)
+    return out @ p["wo"].astype(dt)
+
+
+def _mla_absorbed(p: Params, q_nope, q_pe, c_kv, k_pe, bias,
+                  cfg: ModelConfig) -> jnp.ndarray:
+    """The same attention read from the latent alone: W_kv_b's key half is
+    folded into the query and its value half into the output."""
+    dt, f32 = cfg.compute_dtype, jnp.float32
+    B, Sq, H = q_nope.shape[:3]
+    dn = cfg.qk_nope_head_dim
+    w_kv_b = p["wkv_b"].astype(dt).reshape(cfg.kv_lora_rank, H, -1)
+    q_lat = jnp.einsum("bqhd,chd->bqhc", q_nope, w_kv_b[..., :dn])
+    w = _mla_softmax(
+        jnp.einsum("bqhc,bsc->bhqs", q_lat, c_kv, preferred_element_type=f32),
+        jnp.einsum("bqhr,bsr->bhqs", q_pe, k_pe, preferred_element_type=f32),
+        bias, cfg)
+    o_lat = jnp.einsum("bhqs,bsc->bqhc", w, c_kv)
+    out = jnp.einsum("bqhc,chd->bqhd", o_lat, w_kv_b[..., dn:])
+    return out.reshape(B, Sq, -1) @ p["wo"].astype(dt)
+
+
+def _mla_causal(p: Params, x: jnp.ndarray, cfg: ModelConfig):
+    """Causal latent attention over x from position 0, expanded: (out,
+    c_kv, k_pe)."""
+    x = x.astype(cfg.compute_dtype)
+    S = x.shape[1]
+    q_nope, q_pe, c_kv, k_pe = _mla_project(p, x, jnp.arange(S)[None, :], cfg)
+    out = _mla_expanded(p, q_nope, q_pe, c_kv, k_pe,
+                        _mask_bias(S, S, True, 0), cfg)
+    return out, c_kv, k_pe
+
+
+def apply_mla(p: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """Full-sequence causal latent attention (train)."""
+    with jax.named_scope("mla"):
+        return _mla_causal(p, x, cfg)[0]
+
+
+def prefill_mla(p: Params, x: jnp.ndarray, layer_cache: Dict[str, jnp.ndarray],
+                cfg: ModelConfig) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """Whole-prompt latent attention from position 0, expanded; c_kv and
+    k_pe written to cache rows [0, S) by an elementwise select, as
+    ``prefill_attention`` writes K/V."""
+    with jax.named_scope("mla"):
+        out, c_kv, k_pe = _mla_causal(p, x, cfg)
+        S, S_max = x.shape[1], layer_cache["c_kv"].shape[1]
+        fresh = (jnp.arange(S_max) < S)[None, :, None]
+        rows = ((0, 0), (0, S_max - S), (0, 0))
+        new = {n: jnp.where(fresh, jnp.pad(t.astype(layer_cache[n].dtype), rows),
+                            layer_cache[n])
+               for n, t in (("c_kv", c_kv), ("k_pe", k_pe))}
+    return out, new
+
+
+def decode_mla(p: Params, x: jnp.ndarray, layer_cache: Dict[str, jnp.ndarray],
+               pos: jnp.ndarray, cfg: ModelConfig
+               ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """One-token latent attention, absorbed: write c_kv and k_pe at ``pos``
+    (the elementwise select of ``decode_attention``) and attend over the
+    latent cache of positions <= pos."""
+    x = x.astype(cfg.compute_dtype)
+    B = x.shape[0]
+    with jax.named_scope("mla"):
+        q_nope, q_pe, c_new, k_new = _mla_project(
+            p, x, jnp.full((B, 1), pos, jnp.int32), cfg)
+        S = layer_cache["c_kv"].shape[1]
+        at_pos = (jnp.arange(S) == pos)[None, :, None]
+        new = {"c_kv": jnp.where(at_pos, c_new, layer_cache["c_kv"]),
+               "k_pe": jnp.where(at_pos, k_new, layer_cache["k_pe"])}
+        bias = jnp.where(jnp.arange(S) <= pos, 0.0,
+                         NEG_INF).astype(jnp.float32)[None, :]
+        out = _mla_absorbed(p, q_nope, q_pe, new["c_kv"], new["k_pe"], bias,
+                            cfg)
+    return out, new

@@ -83,6 +83,19 @@ class ModelConfig:
                                      # block outputs to seq-sharded, turning
                                      # their all-reduce into reduce-scatter
     max_seq_len: int = 8192          # learned-pos table size; rope is unbounded
+    # multi-head latent attention (DeepSeek-V2/V3); 0 = grouped-query
+    kv_lora_rank: int = 0            # width of the compressed K/V latent
+    qk_rope_head_dim: int = 0        # rope'd key width, shared by the heads
+    qk_nope_head_dim: int = 0        # per-head query/key width without rope
+    v_head_dim: int = 0              # per-head value width
+    first_dense_layers: int = 0      # leading layers with a dense FFN (moe)
+    # the expert layer: capacity (GShard, softmax then top-k, tokens over
+    # capacity dropped) | dropless (DeepSeek-V3: sigmoid scores plus a
+    # correction bias choose the top-k, pairs sorted by expert, none dropped)
+    moe_dispatch: str = "capacity"
+    routed_scale: float = 1.0        # dropless gates' scale after renormalising
+    experts_held: int = 0            # experts this chip holds; 0 = all
+    first_expert: int = 0            # the first of them
 
     @property
     def dh(self) -> int:
@@ -95,6 +108,10 @@ class ModelConfig:
     @property
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.dh
+
+    @property
+    def n_held(self) -> int:
+        return self.experts_held or self.n_experts
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)

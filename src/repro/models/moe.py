@@ -12,6 +12,15 @@ Supports the two assigned MoE archs:
   * qwen3-moe-30b-a3b — 128 routed top-8, no shared (d_ff 768)
 Routing = softmax-then-topk with renormalized gates (Qwen convention), plus
 the standard load-balancing auxiliary loss (Switch §4) exposed for training.
+
+``cfg.moe_dispatch == "dropless"`` is the other layer (DeepSeek-V3 and
+Moonlight): sigmoid scores, the top-k chosen on scores plus a per-expert
+correction bias, gates renormalised and scaled.  It holds experts
+``first_expert .. first_expert + experts_held - 1`` of the router's
+``n_experts``, routes over all of them, and computes its own experts' part
+for every (token, expert) pair routed to them: pairs are sorted by expert
+and run as grouped GEMMs (``jax.lax.ragged_dot``), so no token is dropped.
+The absent experts' part is left out; nothing stands in for their chips.
 """
 from __future__ import annotations
 
@@ -29,6 +38,8 @@ def _padded_e(cfg: ModelConfig) -> int:
 
 
 def init_moe(key, cfg: ModelConfig) -> Params:
+    if cfg.moe_dispatch == "dropless":
+        return _init_held(key, cfg)
     # optional expert padding: allocating E_pad >= E experts (the extra ones
     # are never routed to) buys EP divisibility on the mesh model axis —
     # e.g. qwen2-moe's 60 experts pad to 64 for a 16-wide axis.  FLOP cost:
@@ -51,6 +62,12 @@ def init_moe(key, cfg: ModelConfig) -> Params:
 
 
 def moe_specs(cfg: ModelConfig) -> Specs:
+    if cfg.moe_dispatch == "dropless":
+        return {"router": ("embed", None), "bias": (None,),
+                "w_gate": ("experts", "embed", "expert_ffn"),
+                "w_up": ("experts", "embed", "expert_ffn"),
+                "w_down": ("experts", "expert_ffn", "embed"),
+                "shared": ffn_mod.ffn_specs(cfg)}
     # "experts" is EP (mesh model axis) when the count divides it; otherwise
     # the launcher maps "expert_ffn" to the model axis instead (per-expert
     # hidden TP — 60-expert qwen2-moe vs a 16-wide axis).
@@ -144,3 +161,92 @@ def apply_moe(p: Params, x: jnp.ndarray, cfg: ModelConfig
     if cfg.n_shared_experts:
         out = out + ffn_mod.apply_ffn(p["shared"], x.astype(dt), cfg)
     return out, aux.astype(jnp.float32)
+
+
+# --- dropless routing over held experts --------------------------------------
+
+#: scale of the seeded per-expert correction bias (a trained model learns it)
+BIAS_SCALE = 0.05
+
+
+def _init_held(key, cfg: ModelConfig) -> Params:
+    """Router over all ``n_experts``, its correction bias, the held experts
+    (expert e's weights from its own key, so a share holds the same numbers
+    as the uncut layer) and the shared experts as one SwiGLU FFN."""
+    D, F = cfg.d_model, cfg.moe_d_ff
+    ks = jax.random.split(key, 6)
+    held = cfg.first_expert + jnp.arange(cfg.n_held)
+
+    def experts(k, d_in, d_out):
+        return jax.vmap(lambda e: jax.random.normal(
+            jax.random.fold_in(k, e), (d_in, d_out)) / jnp.sqrt(d_in))(held)
+
+    return {"router": dense_init(ks[0], D, cfg.n_experts),
+            "bias": jax.random.normal(ks[1], (cfg.n_experts,)) * BIAS_SCALE,
+            "w_gate": experts(ks[2], D, F), "w_up": experts(ks[3], D, F),
+            "w_down": experts(ks[4], F, D),
+            "shared": ffn_mod.init_ffn(ks[5], cfg,
+                                       d_ff=F * cfg.n_shared_experts)}
+
+
+def route_sigmoid(x: jnp.ndarray, p: Params, cfg: ModelConfig
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """x (T, D) -> (gates (T, k), experts (T, k)), all in float32: the
+    router GEMM at full precision, so that bfloat16 rounding flips no
+    choice; top-k of sigmoid scores plus the bias, whose gates are the
+    scores alone, renormalised and scaled."""
+    logits = jnp.dot(x.astype(jnp.float32), p["router"],
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + p["bias"], cfg.moe_top_k)
+    gates = jnp.take_along_axis(scores, idx, axis=-1)
+    gates = gates / (jnp.sum(gates, -1, keepdims=True) + 1e-20)
+    return gates * cfg.routed_scale, idx
+
+
+def _held_chunk(p: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """The held experts' part (T, D) for the tokens x (T, D): every (token,
+    expert) pair routed to a held expert, sorted by expert, through one
+    grouped GEMM per weight."""
+    dt = cfg.compute_dtype
+    T, D = x.shape
+    k, n = cfg.moe_top_k, cfg.n_held
+    gates, idx = route_sigmoid(x, p, cfg)
+    local = idx.reshape(-1) - cfg.first_expert                 # (T*k,)
+    held = (local >= 0) & (local < n)
+    group = jnp.where(held, local, n)             # the others sort last
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.bincount(group, length=n + 1)[:n].astype(jnp.int32)
+    rows = jnp.take(x.astype(dt), order // k, axis=0)          # (T*k, D)
+    g = jax.lax.ragged_dot(rows, p["w_gate"].astype(dt), sizes)
+    u = jax.lax.ragged_dot(rows, p["w_up"].astype(dt), sizes)
+    y = jax.lax.ragged_dot(jax.nn.silu(g) * u, p["w_down"].astype(dt), sizes)
+    # rows past the held pairs belong to no group: the grouped GEMM leaves
+    # them undefined, so they are selected away, never multiplied
+    y = jnp.take(y, jnp.argsort(order), axis=0).reshape(T, k, D)
+    w = jnp.where(held.reshape(T, k), gates, 0.0).astype(dt)
+    return jnp.einsum("tkd,tk->td", jnp.where(held.reshape(T, k, 1), y, 0), w)
+
+
+def apply_held(p: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """x (B, S, D) -> the routed experts' part.  Tokens go in chunks of
+    ``cfg.moe_group_tokens``, which bound the sorted buffers (T*k rows of
+    D), not what is computed."""
+    B, S, D = x.shape
+    T = B * S
+    Tg = min(cfg.moe_group_tokens, T)
+    if T % Tg:
+        Tg = T
+    xt = x.reshape(T // Tg, Tg, D)
+    if T == Tg:
+        out = _held_chunk(p, xt[0], cfg)
+    else:
+        out = jax.lax.map(lambda c: _held_chunk(p, c, cfg), xt)
+    return out.reshape(B, S, D)
+
+
+def apply_dropless(p: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """The whole layer: the held experts' part plus the shared experts
+    for every token."""
+    with jax.named_scope("moe"):
+        return apply_held(p, x, cfg) + ffn_mod.apply_ffn(p["shared"], x, cfg)

@@ -2,18 +2,22 @@
 
 Families:
   dense  — GQA attention + SwiGLU FFN (llama/qwen style)
-  moe    — GQA attention + top-k MoE FFN (shared experts optional)
+  moe    — GQA or latent (MLA) attention + top-k MoE FFN (shared experts
+           optional), after ``cfg.first_dense_layers`` dense layers
   hybrid — Hymba parallel attention ∥ mamba blocks
   ssm    — xLSTM (mLSTM blocks + sLSTM at cfg.slstm_layers), unrolled
 
 Deep homogeneous stacks scan over stacked per-layer params (O(1) HLO size —
 this is what keeps 512-device dry-run compiles tractable and is also the
-production layout).  xLSTM is shallow and heterogeneous -> unrolled.
+production layout).  xLSTM is shallow and heterogeneous -> unrolled.  A
+model whose leading layers are dense scans two stacks, ``dense_blocks``
+then ``blocks``; its cache is then keyed by stack too, as is every latent
+(MLA) cache.
 
 ``forward`` returns (logits, aux) where aux is the MoE load-balance loss
 (0 for non-MoE).  ``decode_step`` performs one-token decode against the
-cache pytree built by ``init_cache``; ``prefill`` fills a dense model's
-cache from a whole prompt in one pass.
+cache pytree built by ``init_cache``; ``prefill`` fills the cache from a
+whole prompt in one pass where ``prefills(cfg)``.
 """
 from __future__ import annotations
 
@@ -35,31 +39,38 @@ from repro.models.common import (ModelConfig, Params, Specs, apply_norm,
 
 # --- block init/specs -------------------------------------------------------------
 
-def init_block(key, cfg: ModelConfig) -> Params:
+#: the scanned stacks of blocks, in layer order
+STACKS = ("dense_blocks", "blocks")
+
+
+def init_block(key, cfg: ModelConfig, moe: bool = True) -> Params:
+    """A block; a moe model's block has a dense FFN where ``moe`` is False."""
     ks = jax.random.split(key, 2)
     if cfg.family == "hybrid":
         return hybrid_mod.init_hymba_block(key, cfg)
     p = {
         "attn_norm": init_norm(cfg),
-        "attn": attn_mod.init_attention(ks[0], cfg),
+        "attn": (attn_mod.init_mla(ks[0], cfg) if cfg.kv_lora_rank
+                 else attn_mod.init_attention(ks[0], cfg)),
         "ffn_norm": init_norm(cfg),
     }
-    if cfg.family == "moe":
+    if cfg.family == "moe" and moe:
         p["moe"] = moe_mod.init_moe(ks[1], cfg)
     else:
         p["ffn"] = ffn_mod.init_ffn(ks[1], cfg)
     return p
 
 
-def block_specs(cfg: ModelConfig) -> Specs:
+def block_specs(cfg: ModelConfig, moe: bool = True) -> Specs:
     if cfg.family == "hybrid":
         return hybrid_mod.hymba_block_specs(cfg)
     p = {
         "attn_norm": norm_specs(cfg),
-        "attn": attn_mod.attention_specs(cfg),
+        "attn": (attn_mod.mla_specs(cfg) if cfg.kv_lora_rank
+                 else attn_mod.attention_specs(cfg)),
         "ffn_norm": norm_specs(cfg),
     }
-    if cfg.family == "moe":
+    if cfg.family == "moe" and moe:
         p["moe"] = moe_mod.moe_specs(cfg)
     else:
         p["ffn"] = ffn_mod.ffn_specs(cfg)
@@ -89,9 +100,16 @@ def init_lm(key, cfg: ModelConfig) -> Params:
                        for i in range(cfg.n_layers)]
     elif cfg.scan_layers:
         blk_keys = jnp.stack(ks[1:1 + cfg.n_layers])
+        F = cfg.first_dense_layers
+        if F:
+            p["dense_blocks"] = jax.vmap(
+                lambda k: init_block(k, cfg, moe=False))(blk_keys[:F])
+            blk_keys = blk_keys[F:]
         p["blocks"] = jax.vmap(lambda k: init_block(k, cfg))(blk_keys)
     else:
-        p["blocks"] = [init_block(ks[1 + i], cfg) for i in range(cfg.n_layers)]
+        p["blocks"] = [init_block(ks[1 + i], cfg,
+                                  moe=i >= cfg.first_dense_layers)
+                       for i in range(cfg.n_layers)]
     p["final_norm"] = init_norm(cfg)
     if not cfg.tie_embeddings:
         p["lm_head"] = dense_init(ks[-1], cfg.d_model, cfg.vocab_size)
@@ -105,11 +123,18 @@ def lm_specs(cfg: ModelConfig) -> Specs:
     if cfg.family == "ssm":
         p["blocks"] = [xlstm_block_specs(cfg, i) for i in range(cfg.n_layers)]
     else:
+        F = cfg.first_dense_layers
         blk = block_specs(cfg)
         if cfg.scan_layers:
-            blk = jax.tree.map(lambda axes: ("layers",) + tuple(axes), blk,
-                               is_leaf=lambda x: isinstance(x, tuple))
-        p["blocks"] = blk if cfg.scan_layers else [blk] * cfg.n_layers
+            stacked = lambda t: jax.tree.map(
+                lambda axes: ("layers",) + tuple(axes), t,
+                is_leaf=lambda x: isinstance(x, tuple))
+            if F:
+                p["dense_blocks"] = stacked(block_specs(cfg, moe=False))
+            p["blocks"] = stacked(blk)
+        else:
+            p["blocks"] = ([block_specs(cfg, moe=False)] * F
+                           + [blk] * (cfg.n_layers - F))
     p["final_norm"] = norm_specs(cfg)
     if not cfg.tie_embeddings:
         p["lm_head"] = ("embed", "vocab")
@@ -132,8 +157,11 @@ def _embed(params: Params, tokens: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray
 def _apply_dense_block(blk: Params, x: jnp.ndarray, cfg: ModelConfig
                        ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     h = apply_norm(blk["attn_norm"], x, cfg)
-    a = attn_mod.apply_attention(blk["attn"], h, cfg,
-                                 window=cfg.sliding_window)
+    if cfg.kv_lora_rank:
+        a = attn_mod.apply_mla(blk["attn"], h, cfg)
+    else:
+        a = attn_mod.apply_attention(blk["attn"], h, cfg,
+                                     window=cfg.sliding_window)
     if cfg.sp_outputs:
         # Megatron-SP: constrain the row-parallel sublayer OUTPUT (a partial
         # sum over the model axis) to seq-sharded before the residual add —
@@ -145,14 +173,21 @@ def _apply_dense_block(blk: Params, x: jnp.ndarray, cfg: ModelConfig
     return _apply_ffn_residual(blk, x, cfg)
 
 
+def _ffn(blk: Params, h: jnp.ndarray, cfg: ModelConfig
+         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The FFN (or MoE) of a block: (out, aux loss)."""
+    if "moe" not in blk:
+        return ffn_mod.apply_ffn(blk["ffn"], h, cfg), jnp.float32(0.0)
+    if cfg.moe_dispatch == "dropless":
+        return moe_mod.apply_dropless(blk["moe"], h, cfg), jnp.float32(0.0)
+    return moe_mod.apply_moe(blk["moe"], h, cfg)
+
+
 def _apply_ffn_residual(blk: Params, x: jnp.ndarray, cfg: ModelConfig
                         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """A dense block's second half: norm, FFN (or MoE) and the residual."""
     h = apply_norm(blk["ffn_norm"], x, cfg)
-    if "moe" in blk:
-        out, aux = moe_mod.apply_moe(blk["moe"], h, cfg)
-    else:
-        out, aux = ffn_mod.apply_ffn(blk["ffn"], h, cfg), jnp.float32(0.0)
+    out, aux = _ffn(blk, h, cfg)
     if cfg.sp_outputs:
         out = shard_hint(out, ("batch", "seq", "embed"))
     x = shard_hint(x + out, ("batch", "seq", "embed"))
@@ -214,7 +249,9 @@ def forward(params: Params, tokens: jnp.ndarray, cfg: ModelConfig
             return (x, aux + a), None
 
         if cfg.scan_layers:
-            (x, aux), _ = jax.lax.scan(body, (x, aux), params["blocks"])
+            for name in STACKS:
+                if name in params:
+                    (x, aux), _ = jax.lax.scan(body, (x, aux), params[name])
         else:
             for blk in params["blocks"]:
                 x, a = _maybe_remat(
@@ -230,6 +267,12 @@ def forward(params: Params, tokens: jnp.ndarray, cfg: ModelConfig
 
 # --- decode ---------------------------------------------------------------------------
 
+def _keyed(cfg: ModelConfig) -> bool:
+    """Whether the cache is keyed by stack (``STACKS``), as a latent (MLA)
+    cache is."""
+    return bool(cfg.kv_lora_rank or cfg.first_dense_layers)
+
+
 def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Dict[str, Any]:
     if cfg.family == "ssm":
         cache: Dict[str, Any] = {}
@@ -242,42 +285,82 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Dict[str, Any]:
         return cache
     if cfg.family == "hybrid":
         return hybrid_mod.init_hymba_cache(cfg, batch, max_len)
-    return attn_mod.init_kv_cache(cfg, batch, max_len)
+    if not _keyed(cfg):
+        return attn_mod.init_kv_cache(cfg, batch, max_len)
+    if not cfg.scan_layers:
+        raise ValueError("a cache keyed by stack needs scan_layers")
+    F = cfg.first_dense_layers
+    cache = {}
+    for name, n in (("dense_blocks", F), ("blocks", cfg.n_layers - F)):
+        if n:
+            cache[name] = (
+                attn_mod.init_latent_cache(cfg, batch, max_len, n)
+                if cfg.kv_lora_rank else
+                attn_mod.init_kv_cache(cfg, batch, max_len, n_layers=n))
+    return cache
+
+
+def prefills(cfg: ModelConfig) -> bool:
+    """Whether ``prefill`` takes this model: attention caches only, and no
+    layer whose result depends on how many tokens a call holds (the
+    capacity of a GShard expert layer does)."""
+    if cfg.family == "dense":
+        return True
+    return cfg.family == "moe" and cfg.moe_dispatch == "dropless"
+
+
+def _over_layers(step, x: jnp.ndarray, params: Params, cache: Dict[str, Any],
+                 cfg: ModelConfig):
+    """x through every block with its layer's cache, by stack:
+    ``step(x, blk, layer_cache) -> (x, new layer cache)``.  Returns (x,
+    the new cache)."""
+    if not _keyed(cfg):
+        return _over_stack(step, x, params["blocks"], cache, cfg)
+    new = {}
+    for name in STACKS:
+        if name in params:
+            x, new[name] = _over_stack(step, x, params[name], cache[name], cfg)
+    return x, new
+
+
+def _over_stack(step, x, blocks, cache, cfg: ModelConfig):
+    if cfg.scan_layers:
+        return jax.lax.scan(lambda x, inp: step(x, *inp), x, (blocks, cache))
+    outs = []
+    for i, blk in enumerate(blocks):
+        x, layer_cache = step(x, blk, jax.tree.map(lambda a: a[i], cache))
+        outs.append(layer_cache)
+    return x, jax.tree.map(lambda *a: jnp.stack(a), *outs)
 
 
 def prefill(params: Params, tokens: jnp.ndarray, cache: Dict[str, Any],
             cfg: ModelConfig) -> Tuple[jnp.ndarray, Dict[str, Any]]:
-    """tokens (B, S) + an empty KV cache -> (logits (B, S, V), the cache
-    with rows [0, S) written): the whole prompt in one causal pass, the
-    same numbers as S ``decode_step`` calls at positions 0..S-1.  Dense
-    family only: the others carry recurrent state or route tokens."""
-    if cfg.family != "dense":
-        raise ValueError(f"prefill is for the dense family, not {cfg.family}")
+    """tokens (B, S) + an empty cache -> (logits (B, S, V), the cache with
+    rows [0, S) written): the whole prompt in one causal pass, the same
+    numbers as S ``decode_step`` calls at positions 0..S-1.  For the models
+    ``prefills`` names: the others carry recurrent state or route tokens
+    by how many a call holds."""
+    if not prefills(cfg):
+        raise ValueError(f"prefill does not take {cfg.name} "
+                         f"({cfg.family}, {cfg.moe_dispatch} dispatch)")
     x = _embed(params, tokens, cfg)
     x = shard_hint(x, ("batch", "seq", "embed"))
 
-    def body(x, inp):
-        blk, krow, vrow = inp
+    def step(x, blk, layer_cache):
         h = apply_norm(blk["attn_norm"], x, cfg)
-        a, kv = attn_mod.prefill_attention(
-            blk["attn"], h, {"k": krow, "v": vrow}, cfg,
-            window=cfg.sliding_window)
+        if cfg.kv_lora_rank:
+            a, layer_cache = attn_mod.prefill_mla(blk["attn"], h, layer_cache,
+                                                  cfg)
+        else:
+            a, layer_cache = attn_mod.prefill_attention(
+                blk["attn"], h, layer_cache, cfg, window=cfg.sliding_window)
         x, _ = _apply_ffn_residual(blk, x + a, cfg)
-        return x, (kv["k"], kv["v"])
+        return x, layer_cache
 
-    if cfg.scan_layers:
-        x, (k, v) = jax.lax.scan(
-            body, x, (params["blocks"], cache["k"], cache["v"]))
-    else:
-        kvs = []
-        for i, blk in enumerate(params["blocks"]):
-            x, kv = body(x, (blk, cache["k"][i], cache["v"][i]))
-            kvs.append(kv)
-        k, v = (jnp.stack(t) for t in zip(*kvs))
-
+    x, new = _over_layers(step, x, params, cache, cfg)
     x = apply_norm(params["final_norm"], x, cfg)
     head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    return x @ head.astype(cfg.compute_dtype), {"k": k, "v": v}
+    return x @ head.astype(cfg.compute_dtype), new
 
 
 def decode_step(params: Params, tokens: jnp.ndarray, cache: Dict[str, Any],
@@ -310,31 +393,21 @@ def decode_step(params: Params, tokens: jnp.ndarray, cache: Dict[str, Any],
                 is_global=i in cfg.global_attn_layers)
             new_cache[f"layer{i}"] = row
     else:
-        def body(x, inp):
-            blk, krow, vrow = inp
+        def step(x, blk, layer_cache):
             h = apply_norm(blk["attn_norm"], x, cfg)
-            a, kv = attn_mod.decode_attention(
-                blk["attn"], h, {"k": krow, "v": vrow}, pos, cfg,
-                window=cfg.sliding_window)
+            if cfg.kv_lora_rank:
+                a, layer_cache = attn_mod.decode_mla(blk["attn"], h,
+                                                     layer_cache, pos, cfg)
+            else:
+                a, layer_cache = attn_mod.decode_attention(
+                    blk["attn"], h, layer_cache, pos, cfg,
+                    window=cfg.sliding_window)
             x = x + a
             h = apply_norm(blk["ffn_norm"], x, cfg)
-            if "moe" in blk:
-                out, _ = moe_mod.apply_moe(blk["moe"], h, cfg)
-            else:
-                out = ffn_mod.apply_ffn(blk["ffn"], h, cfg)
-            return x + out, (kv["k"], kv["v"])
+            out, _ = _ffn(blk, h, cfg)
+            return x + out, layer_cache
 
-        if cfg.scan_layers:
-            x, (k, v) = jax.lax.scan(
-                body, x, (params["blocks"], cache["k"], cache["v"]))
-            new_cache = {"k": k, "v": v}
-        else:
-            ks, vs = [], []
-            for i, blk in enumerate(params["blocks"]):
-                x, (k, v) = body(x, (blk, cache["k"][i], cache["v"][i]))
-                ks.append(k)
-                vs.append(v)
-            new_cache = {"k": jnp.stack(ks), "v": jnp.stack(vs)}
+        x, new_cache = _over_layers(step, x, params, cache, cfg)
 
     x = apply_norm(params["final_norm"], x, cfg)
     head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])

@@ -52,7 +52,7 @@ def init_cache(params, cfg: ModelConfig, batch: int, max_len: int,
 #: numbers the ``serve.generate`` spans of this process
 _CALLS = itertools.count(1)
 
-#: the dense model's whole-prompt prefill, one trace per config and shape
+#: the whole-prompt prefill, one trace per config and shape
 _prefill = jax.jit(lm_mod.prefill, static_argnames="cfg")
 
 
@@ -62,11 +62,11 @@ def greedy_generate(params, cfg: ModelConfig, prompt: jnp.ndarray,
                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Prefill the prompt, then greedy-decode ``steps`` tokens.
 
-    A dense model prefills the whole prompt in one call
-    (``transformer.prefill``); the other families carry recurrent state,
-    route tokens or decode through steps of their own, and prefill token
-    by token.  Returns the tokens (B, S + steps) and the logits
-    (B, S + steps - 1, V), whose column t was computed at position t.
+    A model that ``transformer.prefills`` prefills the whole prompt in one
+    call (``transformer.prefill``); the others carry recurrent state, route
+    tokens by how many a call holds or decode through steps of their own,
+    and prefill token by token.  Returns the tokens (B, S + steps) and the
+    logits (B, S + steps - 1, V), whose column t was computed at position t.
 
     Spans (``repro.obs.trace``): ``serve.generate`` over the whole call;
     ``serve.prefill`` over positions 0..S-1, ending when the first new
@@ -103,7 +103,7 @@ def greedy_generate(params, cfg: ModelConfig, prompt: jnp.ndarray,
 
         positions = steps + prompt.shape[1] - 1
         with trace.span("serve.prefill"):
-            if cfg.family == "dense":
+            if lm_mod.prefills(cfg):
                 with trace.span("serve.prefill_step"), REGISTRY.histogram(
                         "serve.prefill_seconds").time():
                     logits, cache = _prefill(params, prompt, cache, cfg=cfg)
